@@ -524,6 +524,9 @@ def main(quick: bool = False, amo: str = "pairwise",
 
 if __name__ == "__main__":
     import sys
+
+    from repro.core.device import enable_compile_cache
+    enable_compile_cache()
     amo = "sequential" if "--amo=sequential" in sys.argv else "pairwise"
     sizes = None
     bench_out = "BENCH_sweep.json"

@@ -33,4 +33,6 @@ def main(quick: bool = False) -> None:
 
 
 if __name__ == "__main__":
+    from repro.core.device import enable_compile_cache
+    enable_compile_cache()
     main()

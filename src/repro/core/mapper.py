@@ -91,6 +91,10 @@ class IIAttempt:
     # the complete solve that decided this II was seeded with a racer
     # near-miss as CDCL saved phases (None on paths without the session)
     phase_hinted: Optional[bool] = None
+    # first message of the walksat racer exception in this II's sweep
+    # window; set on the window's lowest II only (the verdict is still the
+    # complete solver's)
+    racer_error: Optional[str] = None
 
 
 @dataclass

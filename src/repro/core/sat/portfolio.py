@@ -37,6 +37,7 @@ identical.
 """
 from __future__ import annotations
 
+import logging
 import multiprocessing
 import os
 import threading
@@ -61,6 +62,40 @@ import numpy as np
 from ..cnf import CNF
 
 CANCELLED = "CANCELLED"
+
+_log = logging.getLogger(__name__)
+
+# ------------------------------------------------------------ racer errors
+# The walksat racer is an incomplete leg: its failure must not change a
+# window's verdict (CDCL still proves every candidate), but it must not
+# vanish either — a kernel that fails to compile on the chip would
+# otherwise leave the device idle behind correct answers. Every racer
+# exception is counted here for the whole process (a racer thread may
+# fail after its window has already returned) and logged once; errors
+# raised while the window is open also reach the window's SolveStats.
+_RACER_LOCK = threading.Lock()
+_RACER_ERRORS = 0
+_RACER_FIRST: Optional[str] = None
+
+
+def _note_racer_error(exc: BaseException) -> str:
+    global _RACER_ERRORS, _RACER_FIRST
+    msg = f"{type(exc).__name__}: {exc}"
+    with _RACER_LOCK:
+        _RACER_ERRORS += 1
+        first = _RACER_FIRST is None
+        if first:
+            _RACER_FIRST = msg
+    if first:
+        _log.warning("walksat racer failed; CDCL decides its windows: %s",
+                     msg, exc_info=exc)
+    return msg
+
+
+def racer_errors() -> Tuple[int, Optional[str]]:
+    """(racer exceptions in this process so far, the first message)."""
+    with _RACER_LOCK:
+        return _RACER_ERRORS, _RACER_FIRST
 
 # ------------------------------------------------------------- process pool
 # CPython's GIL serialises the pure-Python CDCL, so concurrent UNSAT proofs
@@ -87,13 +122,6 @@ def _proc_pool() -> Optional[ProcessPoolExecutor]:
         # Callers fall back to threads for this brief window.
         return None
     if _PROC_POOL is None:
-        # jax warns that fork + its internal threads can deadlock the child;
-        # our workers run only the dependency-free pure-Python CDCL and
-        # never call back into XLA, so that hazard doesn't apply — silence
-        # the specific warning rather than scare every sweep user
-        warnings.filterwarnings(
-            "ignore", message=r"os\.fork\(\) was called",
-            category=RuntimeWarning)
         try:
             n = max(2, os.cpu_count() or 2)
             pool = ProcessPoolExecutor(
@@ -102,10 +130,23 @@ def _proc_pool() -> Optional[ProcessPoolExecutor]:
             # Pre-fork every worker NOW, while no racer thread is mid-XLA:
             # lazy forking in a later window could otherwise snapshot a
             # walksat thread holding runtime locks. sleep() keeps all n
-            # tasks occupied long enough that n distinct workers spawn.
-            futures_wait([pool.submit(time.sleep, 0.05) for _ in range(n)])
+            # tasks occupied long enough that n distinct workers spawn
+            # (a fork-context pool starts all of them on the first submit).
+            with warnings.catch_warnings():
+                # JAX warns at every fork once its backend is up (on the
+                # chip host: after this process took the TPU). These
+                # workers run only the pure-Python CDCL and never touch
+                # JAX or the device, so the warning is silenced for these
+                # forks alone — any other fork in the process still warns.
+                warnings.filterwarnings(
+                    "ignore", message=r"os\.fork\(\) was called",
+                    category=RuntimeWarning)
+                futures_wait([pool.submit(time.sleep, 0.05)
+                              for _ in range(n)])
             _PROC_POOL = pool
         except Exception:
+            _log.warning("CDCL process pool unavailable; window proofs "
+                         "run on threads", exc_info=True)
             _PROC_POOL_BROKEN = True
             return None
     return _PROC_POOL
@@ -122,8 +163,11 @@ def _reset_pool() -> None:
     if pool is None:
         return
     try:
+        # SIGKILL: the workers hold nothing worth a clean exit, and on a
+        # chip host they inherit the TPU runtime's SIGTERM handler, which
+        # would print a crash report per worker
         for p in list(getattr(pool, "_processes", {}).values()):
-            p.terminate()
+            p.kill()
         pool.shutdown(wait=False, cancel_futures=True)
     except Exception:
         pass
@@ -173,6 +217,9 @@ class SolveStats:
     # the walksat leg reused a cached dense pack of this II's projection
     # instead of re-packing (None = no walksat leg ran)
     pack_reused: Optional[bool] = None
+    # first message of a walksat racer exception in this candidate's
+    # window (solve_window reports it on the window's first candidate)
+    racer_error: Optional[str] = None
 
 
 class SolverSession:
@@ -545,6 +592,7 @@ def solve_window(cnfs: List[CNF], *, method: str = "auto", seed: int = 0,
     K = len(cnfs)
     t0 = time.time()
     results: List[Optional[WindowResult]] = [None] * K
+    racer_error: List[str] = []      # set while the window is open
     stops = [threading.Event() for _ in range(K)]
     closed = threading.Event()
     lock = threading.Lock()
@@ -649,8 +697,13 @@ def solve_window(cnfs: List[CNF], *, method: str = "auto", seed: int = 0,
                 on_near_miss=on_near_miss_cb if session is not None
                 else None,
                 packed=packed, packs=hpacks)
-        except Exception:   # incomplete leg must never take down the window
-            pass
+        except Exception as exc:
+            # the incomplete leg never takes down the window, but its
+            # failure is counted and reported, never swallowed
+            msg = _note_racer_error(exc)
+            with lock:
+                if not closed.is_set():
+                    racer_error.append(msg)
         if session is not None:
             # this racer thread is deliberately unjoined and may drain
             # after solve_window has returned — near-misses from a closed
@@ -884,6 +937,11 @@ def solve_window(cnfs: List[CNF], *, method: str = "auto", seed: int = 0,
                 results[i] = WindowResult(
                     CANCELLED if via == "cancel" else UNKNOWN,
                     None, via, time.time() - t0)
+        if racer_error and K:
+            first = results[0]
+            if first.stats is None:
+                first.stats = SolveStats(via=first.via)
+            first.stats.racer_error = racer_error[0]
     if flip_thread is not None:
         # the flip racer polls its stop event every few hundred CDCL
         # ticks, so this join is short; joining keeps flip threads from

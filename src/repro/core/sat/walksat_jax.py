@@ -184,26 +184,57 @@ def _sat_kernels_mode() -> Optional[str]:
 
     ``REPRO_SAT_KERNELS`` overrides: ``0``/``off`` => jnp everywhere,
     ``interpret`` => interpret-mode kernels, ``1``/``compiled`` => compiled.
+    On a TPU the kernels never run in interpret mode: asking for it (here
+    or through ``REPRO_PALLAS_INTERPRET``) raises.
     """
     env = os.environ.get("REPRO_SAT_KERNELS", "").strip().lower()
+    backend = jax.default_backend()
     if env in ("0", "false", "off", "jnp"):
         return None
     if env == "interpret":
-        return "interpret"
-    if env in ("1", "true", "on", "compiled"):
-        return "auto"
-    return "auto" if jax.default_backend() in ("tpu", "gpu") else None
+        mode = "interpret"
+    elif env in ("1", "true", "on", "compiled"):
+        mode = "auto"
+    else:
+        mode = "auto" if backend in ("tpu", "gpu") else None
+    if backend == "tpu" and mode is not None:
+        from ...kernels.clause_eval import resolve_interpret
+        if mode == "interpret" or resolve_interpret(None):
+            raise RuntimeError(
+                "interpret-mode Pallas kernels requested on a TPU "
+                "(REPRO_SAT_KERNELS / REPRO_PALLAS_INTERPRET); the device "
+                "walk runs the compiled kernels only")
+    return mode
+
+
+def _batch_sharded(fn, mesh, n_replicated: int, n_batch: int,
+                   n_out: int):
+    """Run a kernel wrapper per device on its slice of the restart batch
+    (axis 1 of every batched operand). A ``pallas_call`` is not
+    partitioned by GSPMD, so on a sharded walk each device must see only
+    its own chains; without a mesh ``fn`` is returned as is."""
+    if mesh is None:
+        return fn
+    from jax.sharding import PartitionSpec as P
+    bat = P(None, "dev")
+    return jax.shard_map(
+        fn, mesh=mesh,
+        in_specs=(P(),) * n_replicated + (bat,) * n_batch,
+        out_specs=bat if n_out == 1 else (bat,) * n_out,
+        check_vma=False)   # pallas_call outputs carry no varying-axes type
 
 
 def _window_tc(cvars: jnp.ndarray, csign: jnp.ndarray, assign: jnp.ndarray,
-               kernels: Optional[str]) -> jnp.ndarray:
+               kernels: Optional[str], mesh=None) -> jnp.ndarray:
     """Window true counts [K, B, C] — the inner evaluation of the sweep,
     routed through the Pallas ``clause_eval`` kernel when enabled."""
     if kernels is not None:
         from ...kernels.clause_eval import true_counts_window
-        return true_counts_window(
-            cvars, csign, assign,
-            interpret=True if kernels == "interpret" else None)
+        interpret = True if kernels == "interpret" else None
+        return _batch_sharded(
+            lambda cv, cs, a: true_counts_window(cv, cs, a,
+                                                 interpret=interpret),
+            mesh, 2, 1, 1)(cvars, csign, assign)
 
     def per_k(cv, cs, a):                     # a: [B, V+1]
         mask = cv > 0
@@ -263,7 +294,7 @@ def _apply_flip_one(ovars, osign, assign, tc, v_flip, new_val):
 
 
 def _window_chunk(cvars, csign, ovars, osign, assign, tc, keys, n_steps, cb,
-                  kernels: Optional[str]):
+                  kernels: Optional[str], mesh=None):
     """Walk all K CNFs for ``n_steps`` probSAT steps (n_steps may be a
     traced scalar — both engines share this one implementation, so they
     consume the PRNG stream identically and stay bit-compatible).
@@ -283,9 +314,10 @@ def _window_chunk(cvars, csign, ovars, osign, assign, tc, keys, n_steps, cb,
             kk = jnp.arange(assign.shape[0])[:, None]
             occ_c = ovars[kk, v_flip]          # [K, B, O]
             occ_s = osign[kk, v_flip]
-            assign, tc = flip_update(
-                assign, tc, v_flip, occ_c, occ_s, new_val,
-                interpret=True if kernels == "interpret" else None)
+            interpret = True if kernels == "interpret" else None
+            assign, tc = _batch_sharded(
+                lambda *a: flip_update(*a, interpret=interpret),
+                mesh, 0, 6, 2)(assign, tc, v_flip, occ_c, occ_s, new_val)
         else:
             assign, tc = jax.vmap(_apply_flip_one)(
                 ovars, osign, assign, tc, v_flip, new_val)
@@ -294,12 +326,12 @@ def _window_chunk(cvars, csign, ovars, osign, assign, tc, keys, n_steps, cb,
     return jax.lax.fori_loop(0, n_steps, body, (assign, tc, keys))
 
 
-@functools.partial(jax.jit, static_argnums=(4, 5, 6, 9))
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 9, 10))
 def _run_chains_window(cvars: jnp.ndarray, csign: jnp.ndarray,
                        ovars: jnp.ndarray, osign: jnp.ndarray,
                        n_vars: int, steps: int, cb: float,
                        assign0: jnp.ndarray, keys: jnp.ndarray,
-                       kernels: Optional[str] = None,
+                       kernels: Optional[str] = None, mesh=None,
                        ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One fixed-length chunk of probSAT over a *window* of K CNFs (the
     host engine's unit of work; one jit entry per chunk length).
@@ -309,9 +341,10 @@ def _run_chains_window(cvars: jnp.ndarray, csign: jnp.ndarray,
     per-clause true counts [K, B, C] — the near-miss signal).
     """
     del n_vars
-    tc0 = _window_tc(cvars, csign, assign0, kernels)
+    tc0 = _window_tc(cvars, csign, assign0, kernels, mesh)
     assign, tc, _ = _window_chunk(cvars, csign, ovars, osign,
-                                  assign0, tc0, keys, steps, cb, kernels)
+                                  assign0, tc0, keys, steps, cb, kernels,
+                                  mesh)
     solved = ~jnp.any(tc == 0, axis=-1)
     return solved, assign, tc
 
@@ -427,29 +460,30 @@ def pack_cnf_window(cnfs: List[CNF],
                      jnp.asarray(ovars), jnp.asarray(osign), V, C)
 
 
-def _maybe_shard_window(packed: PackedCNF, assign0: jnp.ndarray,
-                        ) -> jnp.ndarray:
+def _maybe_shard_window(assign0: jnp.ndarray):
     """Shard the (II-window x restart-batch) grid over the device mesh.
 
     On multi-device hosts the restart batch is split across devices (each
     device walks an independent slice of chains; the clause tensors are
     small and replicated) and GSPMD propagates the layout through the
     jitted engines — the per-candidate solved/near-miss reductions become
-    cross-device all-reduces. Single-device hosts (this CPU container)
-    pass through untouched, so the code path is identical everywhere."""
+    cross-device all-reduces, and the Pallas kernels run per device on
+    their batch slice (:func:`_batch_sharded`). Single-device hosts pass
+    through untouched. Returns (assign0, mesh or None)."""
     n_dev = jax.device_count()
     if n_dev <= 1 or assign0.shape[1] % n_dev != 0:
-        return assign0
+        return assign0, None
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     mesh = Mesh(np.asarray(jax.devices()), ("dev",))
-    return jax.device_put(assign0, NamedSharding(mesh, P(None, "dev", None)))
+    return (jax.device_put(assign0,
+                           NamedSharding(mesh, P(None, "dev", None))), mesh)
 
 
 # ---------------------------------------------------------- device engine
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
 def _device_segment(poll_chunks: int, cb: float, kernels: Optional[str],
-                    cvars, csign, ovars, osign, steps, cap, state):
+                    mesh, cvars, csign, ovars, osign, steps, cap, state):
     """Run up to ``poll_chunks`` chunks of the progressive schedule wholly
     on device, early-exiting when every live candidate has a solved chain.
 
@@ -475,7 +509,8 @@ def _device_segment(poll_chunks: int, cb: float, kernels: Optional[str],
         key, kc = jax.random.split(key)
         keys = jax.random.split(kc, K)
         assign, tc, _ = _window_chunk(cvars, csign, ovars, osign,
-                                      assign, tc, keys, chunk, cb, kernels)
+                                      assign, tc, keys, chunk, cb, kernels,
+                                      mesh)
         chain_ok = ~jnp.any(tc == 0, axis=-1)           # [K, B]
         cand_ok = jnp.any(chain_ok, axis=-1)            # [K]
         fresh = cand_ok & ~solved
@@ -513,10 +548,10 @@ def _solve_window_device(cnfs, live, packed, results, *, seed, steps, batch,
         _init_assign(init_keys[j], batch, packed.n_vars,
                      inits[live[j]] if inits is not None else None)
         for j in range(K)])
-    assign0 = _maybe_shard_window(packed, assign0)
+    assign0, mesh = _maybe_shard_window(assign0)
     kernels = _sat_kernels_mode()
     cap, chunk0 = _chunk_plan(steps, packed.n_clauses)
-    tc0 = _window_tc(packed.cvars, packed.csign, assign0, kernels)
+    tc0 = _window_tc(packed.cvars, packed.csign, assign0, kernels, mesh)
     v1 = packed.n_vars + 1
     state = (assign0, tc0, key,
              jnp.int32(0), jnp.int32(chunk0),
@@ -539,7 +574,7 @@ def _solve_window_device(cnfs, live, packed, results, *, seed, steps, batch,
                 if not pending:
                     break
                 state = state[:7] + (jnp.asarray(skip_host),) + state[8:]
-        state = _device_segment(_POLL_CHUNKS, cb, kernels,
+        state = _device_segment(_POLL_CHUNKS, cb, kernels, mesh,
                                 packed.cvars, packed.csign,
                                 packed.ovars, packed.osign,
                                 jnp.int32(steps), jnp.int32(cap), state)
@@ -601,7 +636,7 @@ def _solve_window_host(cnfs, live, packed, results, *, seed, steps, batch,
         _init_assign(init_keys[j], batch, packed.n_vars,
                      inits[live[j]] if inits is not None else None)
         for j in range(K)])
-    assign0 = _maybe_shard_window(packed, assign0)
+    assign0, mesh = _maybe_shard_window(assign0)
     kernels = _sat_kernels_mode()
     cap, chunk = _chunk_plan(steps, packed.n_clauses)
     done = 0
@@ -615,7 +650,7 @@ def _solve_window_host(cnfs, live, packed, results, *, seed, steps, batch,
         keys = jax.random.split(kc, K)
         solved, assign, tc = _run_chains_window(
             packed.cvars, packed.csign, packed.ovars, packed.osign,
-            packed.n_vars, chunk, cb, assign0, keys, kernels)
+            packed.n_vars, chunk, cb, assign0, keys, kernels, mesh)
         solved_np = np.asarray(solved)
         for j in sorted(pending):
             i = live[j]
@@ -728,6 +763,8 @@ def solve_walksat_window(cnfs: List[CNF], *, seed: int = 0,
             live.append(i)
     if not live:
         return results
+    from ..device import require_attached_backend
+    require_attached_backend()
     if engine is None:
         engine = os.environ.get("REPRO_WALKSAT_ENGINE", "device")
     if engine not in ("device", "host"):

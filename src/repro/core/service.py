@@ -162,6 +162,8 @@ class RequestStats:
     learned_retained: int = 0      # learnt DB size after the request
     near_misses: int = 0           # racer near-misses banked as warm state
     phase_hints: int = 0           # CDCL solves seeded from that warm state
+    racer_errors: int = 0          # sweep windows whose walksat racer raised
+    racer_error: Optional[str] = None   # the first such message
     request_time: float = 0.0
 
 
@@ -186,8 +188,10 @@ class ServiceStats:
     pack_evictions: int = 0        # LRU drops from per-session pack caches
     cache_evictions: int = 0
     session_evictions: int = 0
+    racer_errors: int = 0          # summed RequestStats.racer_errors
+    racer_error: Optional[str] = None   # the first message seen
 
-    def snapshot(self) -> Dict[str, int]:
+    def snapshot(self) -> Dict[str, object]:
         return dict(self.__dict__)
 
 
@@ -343,6 +347,7 @@ class MappingService:
             res = map_loop(dfg, cgra, cfg, sweep_width=sweep_width)
             res.service = RequestStats(via="cold",
                                        request_time=time.time() - t0)
+            self._count_racer_errors(res)
         else:
             entry, reused, skey = self._session_for(dfg, cgra, cfg)
             with entry.lock:
@@ -387,6 +392,7 @@ class MappingService:
                                            witness=witnesses.get(ii)):
                         with self._lock:
                             self.stats.cores_persisted += 1
+            self._count_racer_errors(res)
             with self._lock:
                 self.stats.iis_pruned += res.service.iis_pruned
                 self.stats.clauses_evicted += res.service.clauses_evicted
@@ -408,6 +414,19 @@ class MappingService:
                 with self._lock:
                     self.stats.disk_writes += 1
         return res
+
+    def _count_racer_errors(self, res: MappingResult) -> None:
+        """Carry the sweep's racer errors into the request and service
+        counters (the verdict itself is the complete solver's)."""
+        errs = [a.racer_error for a in res.attempts if a.racer_error]
+        if not errs:
+            return
+        res.service.racer_errors = len(errs)
+        res.service.racer_error = errs[0]
+        with self._lock:
+            self.stats.racer_errors += len(errs)
+            if self.stats.racer_error is None:
+                self.stats.racer_error = errs[0]
 
     # ---------------------------------------------------------- inspection
     @property

@@ -223,6 +223,7 @@ def map_sweep(dfg: DFG, cgra: CGRA, cfg: Optional[MapperConfig] = None,
                 att.warm_hamming = r.stats.warm_hamming
                 att.evicted = r.stats.evicted
                 att.phase_hinted = r.stats.phase_hinted
+                att.racer_error = r.stats.racer_error
             if i in placements:
                 att.regalloc_ok = placements[i][1].ok
             res.attempts.append(att)

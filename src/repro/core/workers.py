@@ -18,13 +18,20 @@ parallel (separate processes, no GIL).
 
 Fork-safety: this module's import chain is deliberately jax-free (see the
 note in ``core/sat/portfolio.py``) — shards fork *clean* and only a
-shard's own walksat racer ever initialises XLA, inside the child. Where
-fork is unavailable (or ``inline=True``), the pool degrades to
-single-worker *thread* shards over one shared thread-safe service: same
-API, same affinity serialisation, no process isolation.
+shard's own walksat racer ever initialises XLA, inside the child.
+
+One process per chip: an accelerator belongs to one process at a time, so
+forked shards cannot each drive it (the second one would find the chip
+taken and walk on the CPU, or fail). On a host with an accelerator
+(:func:`repro.core.device.attached_platform`) the pool therefore runs its
+shards as *threads* of the calling process, which then owns the chip —
+the same inline mode used where fork is unavailable (or ``inline=True``):
+single-worker thread shards over one shared thread-safe service, same API,
+same affinity serialisation, no process isolation.
 """
 from __future__ import annotations
 
+import logging
 import multiprocessing
 import os
 import struct
@@ -32,11 +39,14 @@ from concurrent.futures import Future, ProcessPoolExecutor, ThreadPoolExecutor
 from typing import Dict, List, Optional
 
 from .cgra import CGRA
+from .device import attached_platform
 from .dfg import DFG
 from .mapper import MapperConfig, MappingResult
 from .service import (MappingService, near_shape_key, shape_signature,
                       topology_signature)
 from .store import MappingStore, key_hash
+
+_log = logging.getLogger(__name__)
 
 # ------------------------------------------------- worker-process globals
 
@@ -78,8 +88,9 @@ class WorkerPool:
 
     ``submit()`` returns a ``concurrent.futures.Future`` resolving to the
     shard's :class:`MappingResult`; ``map()`` is the blocking convenience.
-    ``workers=0`` (or fork unavailable) runs inline thread shards over one
-    shared service — identical semantics minus process isolation.
+    ``workers=0``, an accelerator on this host, or fork unavailable runs
+    inline thread shards over one shared service — identical semantics
+    minus process isolation.
     """
 
     def __init__(self, workers: Optional[int] = None,
@@ -91,7 +102,9 @@ class WorkerPool:
         self.n_workers = max(1, workers)
         self.store_path = store_path
         self.near_delta = near_delta
-        self.inline = inline or workers == 0
+        # on an accelerator host the chip must stay with this process
+        self.inline = (inline or workers == 0
+                       or attached_platform() is not None)
         self._shards: List = []
         self._inline_svc: Optional[MappingService] = None
         if not self.inline:
@@ -109,6 +122,9 @@ class WorkerPool:
                 for f in [ex.submit(os.getpid) for ex in self._shards]:
                     f.result(timeout=60)
             except Exception:
+                _log.warning("WorkerPool: could not fork %d shard "
+                             "process(es); running them as threads of "
+                             "this process", self.n_workers, exc_info=True)
                 for ex in self._shards:
                     ex.shutdown(wait=False, cancel_futures=True)
                 self._shards = []

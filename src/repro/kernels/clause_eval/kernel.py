@@ -1,93 +1,76 @@
 """Pallas kernel: batched clause true-count evaluation.
 
-Accelerator adaptation of the WalkSAT inner loop: the whole assignment
-vector for a block of chains lives in VMEM/shared memory (V bits is tiny —
-a 100k-var instance is 100KB as int8), the clause-literal table streams
-through in [block_c, Lmax] tiles, and each grid cell evaluates a
-[block_b x block_c] tile of the (chain, clause) matrix with a vectorized
-gather. Grid dims are fully parallel — clause tiles are independent. The
-same kernel body lowers via Mosaic on TPU and Triton on GPU; the window
-variant adds a leading CNF grid axis for the II-sweep's stacked formulas.
+Accelerator adaptation of the WalkSAT clause evaluation. A TPU has no
+general in-kernel gather, so the per-literal lookup ``assign[b, var]`` is
+recast as a matrix product. With signed literal ids (``+v`` / ``-v``, 0 =
+padding) the true count of clause ``c`` under assignment ``a`` is
+
+    tc[b, c] = #neg_lits[c] + sum_v a[b, v] * W[v, c],
+    W[v, c]  = sum_l sign(lit[c, l]) * [|lit[c, l]| == v],
+
+because a positive literal adds ``a[v]`` and a negative one adds
+``1 - a[v]``. Each grid cell builds one ``[block_v, block_c]`` tile of the
+signed incidence matrix ``W`` with broadcast compares on the VPU (one pass
+over the clause-length axis) and contracts it against the matching
+``[B, block_v]`` assignment slice on the MXU. The variable-tile axis is the
+innermost, sequential grid axis and accumulates into the resident output
+tile. All operands are small integers, exact in bf16 with f32
+accumulation, so the result is bit-identical to the gather oracle.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _clause_eval_kernel(assign_ref, cvars_ref, csign_ref, out_ref):
-    a = assign_ref[...]                      # [bB, V+1] int8
-    cv = cvars_ref[...]                      # [bC, L] int32
-    cs = csign_ref[...]                      # [bC, L] int8
-    bb = a.shape[0]
-    bc, ll = cv.shape
-    flat = cv.reshape(-1)                    # [bC*L]
-    vals = jnp.take(a, flat, axis=1).reshape(bb, bc, ll)
-    sat = (vals == cs[None]) & (cv[None] > 0)
-    out_ref[...] = jnp.sum(sat, axis=-1, dtype=jnp.int32)
+def _clause_eval_window_kernel(assign_ref, lits_ref, out_ref):
+    a = assign_ref[0]                        # [B, bV] bf16 (0/1)
+    bv = a.shape[1]
+    n_lits, bc = lits_ref.shape[1], lits_ref.shape[2]
+    vbase = pl.program_id(2) * bv
+    vid = jax.lax.broadcasted_iota(jnp.int32, (bv, bc), 0) + vbase
+
+    def body(l, carry):
+        w, neg = carry
+        lit = lits_ref[0, pl.ds(l, 1), :]    # [1, bC] signed var ids
+        is_neg = (lit < 0).astype(jnp.int32)
+        sign = (lit > 0).astype(jnp.int32) - is_neg   # 0 on padding
+        w = w + jnp.where(vid == jnp.abs(lit), sign, 0)
+        return w, neg + is_neg
+
+    w, neg = jax.lax.fori_loop(
+        0, n_lits, body,
+        (jnp.zeros((bv, bc), jnp.int32), jnp.zeros((1, bc), jnp.int32)))
+    part = jnp.dot(a, w.astype(jnp.float32).astype(jnp.bfloat16),
+                   preferred_element_type=jnp.float32).astype(jnp.int32)
+
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        out_ref[0] = jnp.broadcast_to(neg, part.shape)
+
+    out_ref[0] += part
 
 
-def clause_eval_pallas(assign: jnp.ndarray, cvars: jnp.ndarray,
-                       csign: jnp.ndarray, *, block_b: int = 8,
-                       block_c: int = 1024, interpret: bool = False,
-                       ) -> jnp.ndarray:
-    """assign: [B, V+1] int8 (0/1); cvars: [C, L] int32; csign: [C, L] int8.
-    Returns tc [B, C] int32. B % block_b == 0 and C % block_c == 0
-    (ops.true_counts pads)."""
-    b, v1 = assign.shape
-    c, l = cvars.shape
-    grid = (b // block_b, c // block_c)
-    return pl.pallas_call(
-        _clause_eval_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_b, v1), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_c, l), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_c, l), lambda i, j: (j, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_b, block_c), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((b, c), jnp.int32),
-        interpret=interpret,
-    )(assign, cvars, csign)
-
-
-def _clause_eval_window_kernel(assign_ref, cvars_ref, csign_ref, out_ref):
-    a = assign_ref[0]                        # [bB, V+1] int8
-    cv = cvars_ref[0]                        # [bC, L] int32
-    cs = csign_ref[0]                        # [bC, L] int8
-    bb = a.shape[0]
-    bc, ll = cv.shape
-    flat = cv.reshape(-1)
-    vals = jnp.take(a, flat, axis=1).reshape(bb, bc, ll)
-    sat = (vals == cs[None]) & (cv[None] > 0)
-    out_ref[0] = jnp.sum(sat, axis=-1, dtype=jnp.int32)
-
-
-def clause_eval_window_pallas(assign: jnp.ndarray, cvars: jnp.ndarray,
-                              csign: jnp.ndarray, *, block_b: int = 8,
-                              block_c: int = 1024, interpret: bool = False,
-                              ) -> jnp.ndarray:
-    """Window variant for the II sweep's stacked formulas: assign
-    [K, B, V+1] int8; cvars/csign [K, C, L]. Returns tc [K, B, C] int32.
-    The CNF axis K is a leading (fully parallel) grid dimension — each grid
-    cell sees one formula's clause tile against one batch tile of its
-    chains. B % block_b == 0 and C % block_c == 0 (ops pads)."""
-    k, b, v1 = assign.shape
-    _, c, l = cvars.shape
-    grid = (k, b // block_b, c // block_c)
+def clause_eval_window_pallas(assign: jnp.ndarray, lits: jnp.ndarray, *,
+                              block_c: int = 256, block_v: int = 128,
+                              interpret: bool = False) -> jnp.ndarray:
+    """assign [K, B, Vp] bf16 (0/1, variable axis padded to ``block_v``);
+    lits [K, L, C] int32 signed literal ids, clause axis last (0 =
+    padding). Returns tc [K, B, C] int32. C % block_c == 0, Vp % block_v
+    == 0 and B % 16 == 0 (ops pads). Grid (K, C tiles, V tiles); the V
+    axis accumulates into the output tile and must stay sequential."""
+    k, b, vp = assign.shape
+    _, l, c = lits.shape
+    grid = (k, c // block_c, vp // block_v)
     return pl.pallas_call(
         _clause_eval_window_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_b, v1), lambda g, i, j: (g, i, 0)),
-            pl.BlockSpec((1, block_c, l), lambda g, i, j: (g, j, 0)),
-            pl.BlockSpec((1, block_c, l), lambda g, i, j: (g, j, 0)),
+            pl.BlockSpec((1, b, block_v), lambda g, j, v: (g, 0, v)),
+            pl.BlockSpec((1, l, block_c), lambda g, j, v: (g, 0, j)),
         ],
-        out_specs=pl.BlockSpec((1, block_b, block_c),
-                               lambda g, i, j: (g, i, j)),
+        out_specs=pl.BlockSpec((1, b, block_c), lambda g, j, v: (g, 0, j)),
         out_shape=jax.ShapeDtypeStruct((k, b, c), jnp.int32),
         interpret=interpret,
-    )(assign, cvars, csign)
+    )(assign, lits)

@@ -7,7 +7,7 @@ import os
 import jax
 import jax.numpy as jnp
 
-from .kernel import clause_eval_pallas, clause_eval_window_pallas
+from .kernel import clause_eval_window_pallas
 from .ref import true_counts_ref, true_counts_window_ref
 
 
@@ -35,51 +35,47 @@ def resolve_interpret(interpret: bool | None) -> bool:
     return jax.default_backend() not in ("tpu", "gpu")
 
 
-@functools.partial(jax.jit, static_argnames=("block_b", "block_c",
+@functools.partial(jax.jit, static_argnames=("block_c", "block_v",
                                              "interpret"))
-def true_counts(cvars: jnp.ndarray, csign: jnp.ndarray, assign: jnp.ndarray,
-                *, block_b: int = 8, block_c: int = 1024,
-                interpret: bool | None = None) -> jnp.ndarray:
-    """Batched per-clause true counts. cvars [C,L] int32 (0-padded, 1-based);
-    csign [C,L] bool; assign [B,V+1] bool -> [B,C] int32.
+def true_counts_window(cvars: jnp.ndarray, csign: jnp.ndarray,
+                       assign: jnp.ndarray, *, block_c: int = 256,
+                       block_v: int = 128,
+                       interpret: bool | None = None) -> jnp.ndarray:
+    """Window true counts: cvars [K,C,L] int32 (1-based, 0 = padding);
+    csign [K,C,L] bool; assign [K,B,V+1] bool -> [K,B,C] int32. The
+    sweep's padded window tensors are already bucketed, but arbitrary
+    shapes are padded here too so the tests can drive odd sizes.
 
     Compiled on TPU/GPU, interpret mode elsewhere (see
     :func:`resolve_interpret`); ``interpret=False`` forces compilation.
     """
     interpret = resolve_interpret(interpret)
-    b, v1 = assign.shape
-    c, l = cvars.shape
-    bp = _pad_to(max(b, 1), block_b)
-    cp = _pad_to(max(c, 1), block_c)
-    a8 = jnp.pad(assign.astype(jnp.int8), ((0, bp - b), (0, 0)))
-    cv = jnp.pad(cvars, ((0, cp - c), (0, 0)))
-    cs = jnp.pad(csign.astype(jnp.int8), ((0, cp - c), (0, 0)))
-    tc = clause_eval_pallas(a8, cv, cs, block_b=block_b, block_c=block_c,
-                            interpret=interpret)
-    return tc[:b, :c]
-
-
-@functools.partial(jax.jit, static_argnames=("block_b", "block_c",
-                                             "interpret"))
-def true_counts_window(cvars: jnp.ndarray, csign: jnp.ndarray,
-                       assign: jnp.ndarray, *, block_b: int = 8,
-                       block_c: int = 1024,
-                       interpret: bool | None = None) -> jnp.ndarray:
-    """Window variant: cvars [K,C,L] int32; csign [K,C,L] bool; assign
-    [K,B,V+1] bool -> [K,B,C] int32. The sweep's padded window tensors are
-    already bucketed, but arbitrary shapes are padded here too so the tests
-    can drive odd sizes."""
-    interpret = resolve_interpret(interpret)
     k, b, v1 = assign.shape
-    _, c, l = cvars.shape
-    bp = _pad_to(max(b, 1), block_b)
+    c = cvars.shape[1]
+    bp = _pad_to(max(b, 1), 16)
     cp = _pad_to(max(c, 1), block_c)
-    a8 = jnp.pad(assign.astype(jnp.int8), ((0, 0), (0, bp - b), (0, 0)))
-    cv = jnp.pad(cvars, ((0, 0), (0, cp - c), (0, 0)))
-    cs = jnp.pad(csign.astype(jnp.int8), ((0, 0), (0, cp - c), (0, 0)))
-    tc = clause_eval_window_pallas(a8, cv, cs, block_b=block_b,
-                                   block_c=block_c, interpret=interpret)
+    vp = _pad_to(v1, block_v)
+    # signed literal ids, clause axis last so a literal slot is one row
+    lits = jnp.where(csign, cvars, -cvars).astype(jnp.int32)
+    lits = jnp.pad(jnp.swapaxes(lits, 1, 2), ((0, 0), (0, 0), (0, cp - c)))
+    a = jnp.pad(assign.astype(jnp.bfloat16),
+                ((0, 0), (0, bp - b), (0, vp - v1)))
+    tc = clause_eval_window_pallas(a, lits, block_c=block_c,
+                                   block_v=block_v, interpret=interpret)
     return tc[:, :b, :c]
+
+
+@functools.partial(jax.jit, static_argnames=("block_c", "block_v",
+                                             "interpret"))
+def true_counts(cvars: jnp.ndarray, csign: jnp.ndarray, assign: jnp.ndarray,
+                *, block_c: int = 256, block_v: int = 128,
+                interpret: bool | None = None) -> jnp.ndarray:
+    """Batched per-clause true counts of one formula. cvars [C,L] int32
+    (0-padded, 1-based); csign [C,L] bool; assign [B,V+1] bool -> [B,C]
+    int32. The K=1 window."""
+    return true_counts_window(cvars[None], csign[None], assign[None],
+                              block_c=block_c, block_v=block_v,
+                              interpret=interpret)[0]
 
 
 __all__ = ["true_counts", "true_counts_window", "true_counts_ref",

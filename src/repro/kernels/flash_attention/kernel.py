@@ -19,9 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams around 0.5; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
 
 _NEG = -2.0 ** 30
 
@@ -108,7 +105,7 @@ def flash_attention_pallas(q, k, v, *, causal=True, window=0, q_offset=0,
             pltpu.VMEM((block_q,), jnp.float32),       # running sum
             pltpu.VMEM((block_q, d), jnp.float32),     # output accumulator
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -22,10 +22,12 @@ from jax.experimental import pallas as pl
 
 def _flip_update_kernel(assign_ref, tc_ref, vflip_ref, occ_ref, osign_ref,
                         newval_ref, assign_out_ref, tc_out_ref):
+    # Mosaic cannot relayout i1 masks built from int8 compares, so the
+    # compares and selects run in int32; only the stored assignment is int8
     tc = tc_ref[0]                           # [bB, bC] int32
     oc = occ_ref[0]                          # [bB, O] int32, -1 = padding
-    os_ = osign_ref[0]                       # [bB, O] int8
-    nv = newval_ref[0]                       # [bB, 1] int8
+    os_ = osign_ref[0].astype(jnp.int32)     # [bB, O] 0/1
+    nv = newval_ref[0].astype(jnp.int32)     # [bB, 1] 0/1
     bb, bc = tc.shape
     o = oc.shape[1]
     cbase = pl.program_id(2) * bc
@@ -38,10 +40,10 @@ def _flip_update_kernel(assign_ref, tc_ref, vflip_ref, occ_ref, osign_ref,
 
     @pl.when(pl.program_id(2) == 0)
     def _flip_assign():
-        a = assign_ref[0]                    # [bB, V+1] int8
+        a = assign_ref[0].astype(jnp.int32)  # [bB, V+1] 0/1
         vf = vflip_ref[0]                    # [bB, 1] int32
         vidx = jax.lax.broadcasted_iota(jnp.int32, a.shape, 1)
-        assign_out_ref[0] = jnp.where(vidx == vf, nv, a)
+        assign_out_ref[0] = jnp.where(vidx == vf, nv, a).astype(jnp.int8)
 
 
 def flip_update_pallas(assign: jnp.ndarray, tc: jnp.ndarray,

@@ -10,10 +10,8 @@ from __future__ import annotations
 import math
 from typing import Optional, Tuple
 
-import numpy as np
-
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -41,7 +39,7 @@ def _mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
             f"mesh {shape} needs {n} devices, found {len(devs)} — the "
             f"dry-run must set XLA_FLAGS=--xla_force_host_platform_"
             f"device_count before any jax import")
-    try:
-        return jax.make_mesh(shape, axes, devices=devs[:n])
-    except TypeError:  # older make_mesh without devices kwarg
-        return Mesh(np.array(devs[:n]).reshape(shape), axes)
+    # Auto axes: the model code places activations with
+    # with_sharding_constraint, which refers to Auto mesh axes only
+    return jax.make_mesh(shape, axes, devices=devs[:n],
+                         axis_types=(AxisType.Auto,) * len(axes))

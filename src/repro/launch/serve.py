@@ -311,4 +311,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from ..core.device import enable_compile_cache
+    enable_compile_cache()
     main()

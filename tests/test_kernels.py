@@ -6,7 +6,7 @@ import pytest
 
 try:
     from hypothesis import given, settings, strategies as st
-except ImportError:                          # container has no hypothesis
+except ImportError:                          # optional dep: local shim
     from _propshim import given, settings, strategies as st
 
 from repro.kernels.clause_eval import true_counts, true_counts_window
