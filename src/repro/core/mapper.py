@@ -20,6 +20,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
+from . import spans
 from .cgra import CGRA
 from .dfg import DFG
 from .encode import EncoderSession
@@ -95,6 +96,15 @@ class IIAttempt:
     # window; set on the window's lowest II only (the verdict is still the
     # complete solver's)
     racer_error: Optional[str] = None
+    # the device walk for this II (SolveStats.walk_*): probSAT steps and
+    # segments walked while the II was pending (the IIs of one sweep
+    # window share one walk), its real clause rows and the padded rows of
+    # the walked pack; None where no walk ran. Results pickled before
+    # these fields existed lack them: read with getattr(att, name, None)
+    walk_steps: Optional[int] = None
+    walk_segments: Optional[int] = None
+    walk_rows: Optional[int] = None
+    walk_rows_padded: Optional[int] = None
 
 
 @dataclass
@@ -142,7 +152,8 @@ def _try_ii(dfg: DFG, cgra: CGRA, ii: int, cfg: MapperConfig,
     a fresh CNF is encoded and solved cold (the reference path)."""
     if sess is not None:
         t0 = time.time()
-        sess.ensure_ii(ii)
+        with spans.span("map.encode"):
+            sess.ensure_ii(ii)
         t_enc = time.time() - t0
         st = sess.stats_for(ii)
         t0 = time.time()
@@ -160,15 +171,21 @@ def _try_ii(dfg: DFG, cgra: CGRA, ii: int, cfg: MapperConfig,
                         conflicts=stats.conflicts,
                         warm_hamming=stats.warm_hamming,
                         evicted=stats.evicted,
-                        phase_hinted=stats.phase_hinted)
+                        phase_hinted=stats.phase_hinted,
+                        walk_steps=stats.walk_steps,
+                        walk_segments=stats.walk_segments,
+                        walk_rows=stats.walk_rows,
+                        walk_rows_padded=stats.walk_rows_padded)
         attempts.append(att)
         if status != SAT:
             return None
-        placement = sess.enc.decode(ii, model)
+        with spans.span("map.decode"):
+            placement = sess.enc.decode(ii, model)
     else:
         t0 = time.time()
-        session = EncoderSession(dfg, cgra, cfg.amo)
-        enc = session.encode(ii)
+        with spans.span("map.encode"):
+            session = EncoderSession(dfg, cgra, cfg.amo)
+            enc = session.encode(ii)
         t_enc = time.time() - t0
         t0 = time.time()
         hint = None
@@ -184,8 +201,10 @@ def _try_ii(dfg: DFG, cgra: CGRA, ii: int, cfg: MapperConfig,
         attempts.append(att)
         if status != SAT:
             return None
-        placement = enc.decode(model)
-    ra = allocate(dfg, cgra, placement, ii)
+        with spans.span("map.decode"):
+            placement = enc.decode(model)
+    with spans.span("map.regalloc"):
+        ra = allocate(dfg, cgra, placement, ii)
     att.regalloc_ok = ra.ok
     if not ra.ok:
         return None
@@ -363,9 +382,11 @@ def map_loop(dfg: DFG, cgra: CGRA, cfg: MapperConfig | None = None,
                     break
         if got is not None:
             placement, ra = got
-            chk = verify_mapping(
-                cur_dfg, cgra, placement, ii, n_iters=cfg.verify_iters,
-                node_subset=set(dfg.nodes) if cur_dfg is not dfg else None)
+            with spans.span("map.verify"):
+                chk = verify_mapping(
+                    cur_dfg, cgra, placement, ii, n_iters=cfg.verify_iters,
+                    node_subset=set(dfg.nodes) if cur_dfg is not dfg
+                    else None)
             if not chk.ok:
                 raise AssertionError(
                     f"mapper produced an invalid mapping at II={ii}: "

@@ -59,6 +59,7 @@ import numpy as np
 # portfolio legs — which the cdcl/z3 worker paths never enter — pay the
 # deferred import.
 
+from .. import spans
 from ..cnf import CNF
 
 CANCELLED = "CANCELLED"
@@ -220,6 +221,18 @@ class SolveStats:
     # first message of a walksat racer exception in this candidate's
     # window (solve_window reports it on the window's first candidate)
     racer_error: Optional[str] = None
+    # the device walk for this candidate (walksat_jax.WalkCounts): steps
+    # and segments walked while it was pending, its real clause rows and
+    # the padded rows of the walked pack (None = it did not walk)
+    walk_steps: Optional[int] = None
+    walk_segments: Optional[int] = None
+    walk_rows: Optional[int] = None
+    walk_rows_padded: Optional[int] = None
+
+    def note_walk(self, counts) -> None:
+        if counts is not None:
+            (self.walk_steps, self.walk_segments, self.walk_rows,
+             self.walk_rows_padded) = counts
 
 
 class SolverSession:
@@ -322,7 +335,8 @@ class SolverSession:
             self._pack_np.move_to_end(ii)
             self.pack_reuses += 1
             return hit[1], True
-        pack = pack_cnf_np(cnf)
+        with spans.span("walk.pack"):
+            pack = pack_cnf_np(cnf)
         self._pack_np[ii] = (key, pack)
         self._pack_np.move_to_end(ii)
         while len(self._pack_np) > self.max_cached_packs:
@@ -417,8 +431,10 @@ class SolverSession:
         stats = SolveStats(via=self.complete_method)
         if self.complete_method == "cdcl":
             stats.learned_retained = backend.n_learnt
-            status, model = backend.solve(assumptions=assumptions, stop=stop,
-                                          phase_hint=phase_hint)
+            with spans.span("sat.cdcl"):
+                status, model = backend.solve(assumptions=assumptions,
+                                              stop=stop,
+                                              phase_hint=phase_hint)
             stats.conflicts = backend.last_conflicts
             stats.evicted = backend.evicted_total or None
         else:
@@ -450,16 +466,18 @@ class SolverSession:
         from .walksat_jax import solve_walksat
         init = self.warm_init()
         near: dict = {}
+        walk: dict = {}
         cnf = self.project(ii)
         pack, reused = self.host_pack(ii)
         status, model = solve_walksat(
             cnf, seed=self.seed, steps=self.walksat_steps,
             batch=self.walksat_batch, stop=stop, init=init, near_miss=near,
-            pack=pack)
+            pack=pack, walk_counts=walk)
         if status == SAT:
             stats = SolveStats(via="walksat", pack_reused=reused)
             if init is not None:
                 stats.warm_hamming = _hamming(init, model)
+            stats.note_walk(walk.get(0))
             self.update_best(model, 0)
             self.n_solves += 1
             return status, model, stats
@@ -467,9 +485,12 @@ class SolverSession:
             self.update_best(near[0][1], near[0][0])
         if self.raw_method == "walksat":
             self.n_solves += 1
-            return status, None, SolveStats(via="walksat",
-                                            pack_reused=reused)
-        return self.solve_complete(ii, stop=stop, phase_hint=phase_hint)
+            stats = SolveStats(via="walksat", pack_reused=reused)
+        else:
+            status, model, stats = self.solve_complete(
+                ii, stop=stop, phase_hint=phase_hint)
+        stats.note_walk(walk.get(0))
+        return status, model, stats
 
     # ------------------------------------------------------------ warm state
     def warm_init(self) -> Optional[List[bool]]:
@@ -593,6 +614,7 @@ def solve_window(cnfs: List[CNF], *, method: str = "auto", seed: int = 0,
     t0 = time.time()
     results: List[Optional[WindowResult]] = [None] * K
     racer_error: List[str] = []      # set while the window is open
+    walked: dict = {}                # i -> WalkCounts, as the walk goes
     stops = [threading.Event() for _ in range(K)]
     closed = threading.Event()
     lock = threading.Lock()
@@ -648,7 +670,11 @@ def solve_window(cnfs: List[CNF], *, method: str = "auto", seed: int = 0,
             return   # cancelled / timed out; filled in at the end
         deliver(i, status, model, method)
 
-    def run_walksat() -> None:
+    def run_walksat(token) -> None:
+        with spans.adopt(token):
+            _run_walksat()
+
+    def _run_walksat() -> None:
         # staged start: no work at all if the complete leg wins the window
         # (or the deadline passes) inside the grace period
         if closed.wait(min(walksat_delay,
@@ -696,7 +722,7 @@ def solve_window(cnfs: List[CNF], *, method: str = "auto", seed: int = 0,
                 near_miss=near if session is not None else None,
                 on_near_miss=on_near_miss_cb if session is not None
                 else None,
-                packed=packed, packs=hpacks)
+                packed=packed, packs=hpacks, walk_counts=walked)
         except Exception as exc:
             # the incomplete leg never takes down the window, but its
             # failure is counted and reported, never swallowed
@@ -724,7 +750,8 @@ def solve_window(cnfs: List[CNF], *, method: str = "auto", seed: int = 0,
         # XLA under a live computation. Started only after the process-pool
         # submissions so worker forks never overlap fresh XLA work.
         if use_walksat and complete:
-            threading.Thread(target=run_walksat, daemon=False).start()
+            threading.Thread(target=run_walksat, args=(spans.handoff(),),
+                             daemon=False).start()
 
     def run_complete_procs(futs: dict) -> None:
         """CDCL leg on the process pool: real parallelism for the UNSAT
@@ -919,7 +946,7 @@ def solve_window(cnfs: List[CNF], *, method: str = "auto", seed: int = 0,
             on_sat=lambda i, model: deliver(i, SAT, model, "walksat"),
             inits=[warm] * K if warm is not None else None,
             near_miss=near if session is not None else None,
-            packed=packed, packs=hpacks)
+            packed=packed, packs=hpacks, walk_counts=walked)
         if session is not None:
             for nu, a in near.values():
                 session.update_best(a, nu)
@@ -937,6 +964,12 @@ def solve_window(cnfs: List[CNF], *, method: str = "auto", seed: int = 0,
                 results[i] = WindowResult(
                     CANCELLED if via == "cancel" else UNKNOWN,
                     None, via, time.time() - t0)
+        for i in range(K):
+            counts = walked.get(i)   # the racer may still be walking
+            if counts is not None:
+                if results[i].stats is None:
+                    results[i].stats = SolveStats(via=results[i].via)
+                results[i].stats.note_walk(counts)
         if racer_error and K:
             first = results[0]
             if first.stats is None:

@@ -46,6 +46,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from .. import spans
 from ..cnf import CNF
 
 _INT32_MAX = np.iinfo(np.int32).max
@@ -75,6 +76,17 @@ def _validate_model(cnf: CNF, model: List[bool], ctx: str) -> None:
             f"walksat returned a non-model ({ctx}): device true-counts "
             f"claim SAT but CNF.check fails on {cnf.n_vars} vars / "
             f"{cnf.n_clauses} clauses")
+
+
+class WalkCounts(NamedTuple):
+    """What one candidate's walk cost: probSAT steps and device segments
+    walked while it was pending (candidates of one window walk together,
+    so they share these), its real clause rows and the window's padded
+    clause rows C."""
+    steps: int
+    segments: int
+    rows: int
+    rows_padded: int
 
 
 class PackedCNF(NamedTuple):
@@ -251,30 +263,33 @@ def _pick_flip_one(cvars, ovars, osign, assign, tc, key, cb):
 
     assign: [B, V+1] bool, tc: [B, C] int32. Returns (v_flip [B] — var 0
     (the dummy) for already-solved chains, new_val [B], key')."""
-    unsat = tc == 0                           # [B, C]
-    any_unsat = jnp.any(unsat, axis=-1)       # [B]
-    key, k1, k2 = jax.random.split(key, 3)
-    # pick a random unsat clause per chain
-    logits = jnp.where(unsat, 0.0, -1e30)
-    cidx = jax.random.categorical(k1, logits, axis=-1)      # [B]
-    vs = cvars[cidx]                          # [B, Lmax]
-    vmask = vs > 0
-    # break count per candidate var: clauses where v is the sole support
-    occ_c = ovars[vs]                         # [B, Lmax, Omax]
-    occ_s = osign[vs]
-    occ_valid = occ_c >= 0
-    occ_cc = jnp.where(occ_valid, occ_c, 0)
-    flat = occ_cc.reshape(occ_cc.shape[0], -1)              # [B, L*O]
-    tc_at = jnp.take_along_axis(tc, flat, axis=-1).reshape(occ_c.shape)
-    a_at = jnp.take_along_axis(assign, vs, axis=-1)         # [B, Lmax]
-    supports = occ_s == a_at[..., None]       # var currently satisfies c'
-    brk = jnp.sum(occ_valid & supports & (tc_at == 1), axis=-1)  # [B, Lmax]
-    # probSAT polynomial heuristic: p ∝ (1 + brk)^-cb
-    w = jnp.where(vmask, -cb * jnp.log1p(brk.astype(jnp.float32)), -1e30)
-    pick = jax.random.categorical(k2, w, axis=-1)           # [B]
-    v_flip = jnp.take_along_axis(vs, pick[:, None], axis=-1)[:, 0]
-    v_flip = jnp.where(any_unsat, v_flip, 0)  # flip dummy var 0 if solved
-    new_val = ~jnp.take_along_axis(assign, v_flip[:, None], axis=-1)[:, 0]
+    with jax.named_scope("walk.pick.clause"):
+        unsat = tc == 0                       # [B, C]
+        any_unsat = jnp.any(unsat, axis=-1)   # [B]
+        key, k1, k2 = jax.random.split(key, 3)
+        # pick a random unsat clause per chain
+        logits = jnp.where(unsat, 0.0, -1e30)
+        cidx = jax.random.categorical(k1, logits, axis=-1)  # [B]
+    with jax.named_scope("walk.pick.break"):
+        vs = cvars[cidx]                      # [B, Lmax]
+        vmask = vs > 0
+        # break count per candidate var: clauses where v is the sole support
+        occ_c = ovars[vs]                     # [B, Lmax, Omax]
+        occ_s = osign[vs]
+        occ_valid = occ_c >= 0
+        occ_cc = jnp.where(occ_valid, occ_c, 0)
+        flat = occ_cc.reshape(occ_cc.shape[0], -1)          # [B, L*O]
+        tc_at = jnp.take_along_axis(tc, flat, axis=-1).reshape(occ_c.shape)
+        a_at = jnp.take_along_axis(assign, vs, axis=-1)     # [B, Lmax]
+        supports = occ_s == a_at[..., None]   # var currently satisfies c'
+        brk = jnp.sum(occ_valid & supports & (tc_at == 1), axis=-1)
+        # probSAT polynomial heuristic: p ∝ (1 + brk)^-cb
+        w = jnp.where(vmask, -cb * jnp.log1p(brk.astype(jnp.float32)),
+                      -1e30)
+        pick = jax.random.categorical(k2, w, axis=-1)       # [B]
+        v_flip = jnp.take_along_axis(vs, pick[:, None], axis=-1)[:, 0]
+        v_flip = jnp.where(any_unsat, v_flip, 0)  # flip dummy var 0 if solved
+        new_val = ~jnp.take_along_axis(assign, v_flip[:, None], axis=-1)[:, 0]
     return v_flip, new_val, key
 
 
@@ -309,18 +324,20 @@ def _window_chunk(cvars, csign, ovars, osign, assign, tc, keys, n_steps, cb,
             lambda cv, ov, os_, a, t, k:
             _pick_flip_one(cv, ov, os_, a, t, k, cb)
         )(cvars, ovars, osign, assign, tc, keys)
-        if kernels is not None:
-            from ...kernels.flip_update import flip_update
-            kk = jnp.arange(assign.shape[0])[:, None]
-            occ_c = ovars[kk, v_flip]          # [K, B, O]
-            occ_s = osign[kk, v_flip]
-            interpret = True if kernels == "interpret" else None
-            assign, tc = _batch_sharded(
-                lambda *a: flip_update(*a, interpret=interpret),
-                mesh, 0, 6, 2)(assign, tc, v_flip, occ_c, occ_s, new_val)
-        else:
-            assign, tc = jax.vmap(_apply_flip_one)(
-                ovars, osign, assign, tc, v_flip, new_val)
+        with jax.named_scope("walk.flip"):
+            if kernels is not None:
+                from ...kernels.flip_update import flip_update
+                kk = jnp.arange(assign.shape[0])[:, None]
+                occ_c = ovars[kk, v_flip]      # [K, B, O]
+                occ_s = osign[kk, v_flip]
+                interpret = True if kernels == "interpret" else None
+                assign, tc = _batch_sharded(
+                    lambda *a: flip_update(*a, interpret=interpret),
+                    mesh, 0, 6, 2)(assign, tc, v_flip, occ_c, occ_s,
+                                   new_val)
+            else:
+                assign, tc = jax.vmap(_apply_flip_one)(
+                    ovars, osign, assign, tc, v_flip, new_val)
         return assign, tc, keys
 
     return jax.lax.fori_loop(0, n_steps, body, (assign, tc, keys))
@@ -341,11 +358,13 @@ def _run_chains_window(cvars: jnp.ndarray, csign: jnp.ndarray,
     per-clause true counts [K, B, C] — the near-miss signal).
     """
     del n_vars
-    tc0 = _window_tc(cvars, csign, assign0, kernels, mesh)
+    with jax.named_scope("walk.init"):
+        tc0 = _window_tc(cvars, csign, assign0, kernels, mesh)
     assign, tc, _ = _window_chunk(cvars, csign, ovars, osign,
                                   assign0, tc0, keys, steps, cb, kernels,
                                   mesh)
-    solved = ~jnp.any(tc == 0, axis=-1)
+    with jax.named_scope("walk.chunk_end"):
+        solved = ~jnp.any(tc == 0, axis=-1)
     return solved, assign, tc
 
 
@@ -429,6 +448,18 @@ def pack_cnf_window(cnfs: List[CNF],
     CNF (``None`` entries are packed here) — the session-level cache path
     that makes warm window solves skip per-CNF packing entirely.
     """
+    with spans.span("walk.pack"):
+        cvars, csign, ovars, osign, V, C = _stack_packs(cnfs, packs)
+    # the span times the enqueue of the copies, not their arrival
+    with spans.span("walk.upload"):
+        return PackedCNF(jnp.asarray(cvars), jnp.asarray(csign),
+                         jnp.asarray(ovars), jnp.asarray(osign), V, C)
+
+
+def _stack_packs(cnfs: List[CNF],
+                 packs: Optional[List[Optional[HostPack]]]):
+    """The numpy half of :func:`pack_cnf_window`: (cvars, csign, ovars,
+    osign, V, C) padded to the window's common bucketed shapes."""
     host: List[HostPack] = []
     for k, c in enumerate(cnfs):
         p = packs[k] if packs is not None else None
@@ -456,8 +487,7 @@ def pack_cnf_window(cnfs: List[CNF],
         v, o = p.ovars.shape
         ovars[k, :v, :o] = p.ovars
         osign[k, :v, :o] = p.osign
-    return PackedCNF(jnp.asarray(cvars), jnp.asarray(csign),
-                     jnp.asarray(ovars), jnp.asarray(osign), V, C)
+    return cvars, csign, ovars, osign, V, C
 
 
 def _maybe_shard_window(assign0: jnp.ndarray):
@@ -498,37 +528,43 @@ def _device_segment(poll_chunks: int, cb: float, kernels: Optional[str],
     """
     K = state[0].shape[0]
 
+    # the chunk's bookkeeping (loop test, key split, solved flags,
+    # snapshots, schedule) is scoped walk.chunk_end; the steps carry
+    # their own walk.pick.* / walk.flip scopes
     def cond(st):
         _, _, _, done, _, solved, _, skip, _, _, polls = st
-        return ((done < steps) & jnp.any(~(solved | skip))
-                & (polls < poll_chunks))
+        with jax.named_scope("walk.chunk_end"):
+            return ((done < steps) & jnp.any(~(solved | skip))
+                    & (polls < poll_chunks))
 
     def body(st):
         (assign, tc, key, done, chunk, solved, solved_assign, skip,
          best_unsat, best_assign, polls) = st
-        key, kc = jax.random.split(key)
-        keys = jax.random.split(kc, K)
+        with jax.named_scope("walk.chunk_end"):
+            key, kc = jax.random.split(key)
+            keys = jax.random.split(kc, K)
         assign, tc, _ = _window_chunk(cvars, csign, ovars, osign,
                                       assign, tc, keys, chunk, cb, kernels,
                                       mesh)
-        chain_ok = ~jnp.any(tc == 0, axis=-1)           # [K, B]
-        cand_ok = jnp.any(chain_ok, axis=-1)            # [K]
-        fresh = cand_ok & ~solved
-        row = jnp.argmax(chain_ok, axis=-1)             # first solved chain
-        snap = assign[jnp.arange(K), row]
-        solved_assign = jnp.where(fresh[:, None], snap, solved_assign)
-        solved = solved | fresh
-        # near-miss: best assignment over all chunks, per still-pending
-        # candidate (solved/skipped candidates stop accumulating)
-        n_unsat = jnp.sum(tc == 0, axis=-1)             # [K, B]
-        bu = jnp.min(n_unsat, axis=-1)
-        brow = jnp.argmin(n_unsat, axis=-1)
-        improve = ~solved & ~skip & (bu < best_unsat)
-        best_unsat = jnp.where(improve, bu, best_unsat)
-        best_assign = jnp.where(improve[:, None],
-                                assign[jnp.arange(K), brow], best_assign)
-        done = done + chunk
-        chunk = _next_chunk_jnp(chunk, cap, steps - done)
+        with jax.named_scope("walk.chunk_end"):
+            chain_ok = ~jnp.any(tc == 0, axis=-1)       # [K, B]
+            cand_ok = jnp.any(chain_ok, axis=-1)        # [K]
+            fresh = cand_ok & ~solved
+            row = jnp.argmax(chain_ok, axis=-1)         # first solved chain
+            snap = assign[jnp.arange(K), row]
+            solved_assign = jnp.where(fresh[:, None], snap, solved_assign)
+            solved = solved | fresh
+            # near-miss: best assignment over all chunks, per still-pending
+            # candidate (solved/skipped candidates stop accumulating)
+            n_unsat = jnp.sum(tc == 0, axis=-1)         # [K, B]
+            bu = jnp.min(n_unsat, axis=-1)
+            brow = jnp.argmin(n_unsat, axis=-1)
+            improve = ~solved & ~skip & (bu < best_unsat)
+            best_unsat = jnp.where(improve, bu, best_unsat)
+            best_assign = jnp.where(improve[:, None],
+                                    assign[jnp.arange(K), brow], best_assign)
+            done = done + chunk
+            chunk = _next_chunk_jnp(chunk, cap, steps - done)
         return (assign, tc, key, done, chunk, solved, solved_assign, skip,
                 best_unsat, best_assign, polls + 1)
 
@@ -538,12 +574,15 @@ def _device_segment(poll_chunks: int, cb: float, kernels: Optional[str],
 
 def _solve_window_device(cnfs, live, packed, results, *, seed, steps, batch,
                          cb, stop, should_skip, on_sat, inits, near_miss,
-                         on_near_miss):
+                         on_near_miss, count):
     from . import SAT
     K = len(live)
     key = jax.random.PRNGKey(seed)
     key, k0 = jax.random.split(key)
     init_keys = jax.random.split(k0, K)
+    # the walk's start runs eagerly, as small programs of its own that
+    # carry no walk.* scope (the host engine's jitted chunk scopes its
+    # true counts walk.init)
     assign0 = jnp.stack([
         _init_assign(init_keys[j], batch, packed.n_vars,
                      inits[live[j]] if inits is not None else None)
@@ -561,7 +600,7 @@ def _solve_window_device(cnfs, live, packed, results, *, seed, steps, batch,
     skip_host = np.zeros(K, bool)
     pending = set(range(K))
     nm_emitted = np.full(K, _INT32_MAX, np.int64)   # last streamed quality
-    done = 0
+    done = segments = 0
     while done < steps and pending:
         if stop is not None and stop():
             break
@@ -574,48 +613,56 @@ def _solve_window_device(cnfs, live, packed, results, *, seed, steps, batch,
                 if not pending:
                     break
                 state = state[:7] + (jnp.asarray(skip_host),) + state[8:]
-        state = _device_segment(_POLL_CHUNKS, cb, kernels, mesh,
-                                packed.cvars, packed.csign,
-                                packed.ovars, packed.osign,
-                                jnp.int32(steps), jnp.int32(cap), state)
-        # the host blocks only on the tiny status pair; the walk state
-        # (assignments, true counts, near-miss buffers) stays on device
-        solved_dev, done_dev = jax.block_until_ready((state[5], state[3]))
+        with spans.span("walk.segment"):
+            state = _device_segment(_POLL_CHUNKS, cb, kernels, mesh,
+                                    packed.cvars, packed.csign,
+                                    packed.ovars, packed.osign,
+                                    jnp.int32(steps), jnp.int32(cap), state)
+            # the host blocks only on the tiny status pair; the walk state
+            # (assignments, true counts, near-miss buffers) stays on device
+            solved_dev, done_dev = jax.block_until_ready((state[5],
+                                                          state[3]))
         solved_np = np.asarray(solved_dev)
         done = int(done_dev)
-        for j in sorted(pending):
-            if not solved_np[j]:
-                continue
-            i = live[j]
-            model = [bool(b) for b in
-                     np.asarray(state[6][j])[1:cnfs[i].n_vars + 1]]
-            _validate_model(cnfs[i], model, f"device engine, candidate {i}")
-            results[i] = (SAT, model)
-            pending.discard(j)
-            if on_sat is not None:
-                on_sat(i, model)
-        if on_near_miss is not None and pending:
-            # stream near-miss improvements at each poll — the caller's
-            # feedback channel (e.g. CDCL phase hints) sees them while
-            # the walk is still running, not only at budget exhaustion
-            bu = np.asarray(state[8])
+        segments += 1
+        count(pending, done, segments)
+        with spans.span("walk.extract"):
             for j in sorted(pending):
-                if bu[j] < nm_emitted[j]:
-                    nm_emitted[j] = bu[j]
-                    i = live[j]
-                    on_near_miss(
-                        i, int(bu[j]),
-                        [bool(b) for b in
-                         np.asarray(state[9][j])[1:cnfs[i].n_vars + 1]])
+                if not solved_np[j]:
+                    continue
+                i = live[j]
+                model = [bool(b) for b in
+                         np.asarray(state[6][j])[1:cnfs[i].n_vars + 1]]
+                _validate_model(cnfs[i], model,
+                                f"device engine, candidate {i}")
+                results[i] = (SAT, model)
+                pending.discard(j)
+                if on_sat is not None:
+                    on_sat(i, model)
+            if on_near_miss is not None and pending:
+                # stream near-miss improvements at each poll — the
+                # caller's feedback channel (e.g. CDCL phase hints) sees
+                # them while the walk is still running, not only at
+                # budget exhaustion
+                bu = np.asarray(state[8])
+                for j in sorted(pending):
+                    if bu[j] < nm_emitted[j]:
+                        nm_emitted[j] = bu[j]
+                        i = live[j]
+                        on_near_miss(
+                            i, int(bu[j]),
+                            [bool(b) for b in
+                             np.asarray(state[9][j])[1:cnfs[i].n_vars + 1]])
     if near_miss is not None and pending:
-        bu = np.asarray(state[8])
-        ba = np.asarray(state[9])
-        for j in sorted(pending):
-            if bu[j] >= _INT32_MAX:
-                continue
-            i = live[j]
-            near_miss[i] = (int(bu[j]),
-                            [bool(b) for b in ba[j][1:cnfs[i].n_vars + 1]])
+        with spans.span("walk.extract"):
+            bu = np.asarray(state[8])
+            ba = np.asarray(state[9])
+            for j in sorted(pending):
+                if bu[j] >= _INT32_MAX:
+                    continue
+                i = live[j]
+                near_miss[i] = (int(bu[j]), [bool(b) for b in
+                                             ba[j][1:cnfs[i].n_vars + 1]])
     return results
 
 
@@ -623,7 +670,7 @@ def _solve_window_device(cnfs, live, packed, results, *, seed, steps, batch,
 
 def _solve_window_host(cnfs, live, packed, results, *, seed, steps, batch,
                        cb, stop, should_skip, on_sat, inits, near_miss,
-                       on_near_miss):
+                       on_near_miss, count):
     """The per-chunk host loop (PR 1/2 reference engine): identical chunk
     schedule, PRNG stream, and near-miss bookkeeping as the device engine,
     with flags polled after every chunk."""
@@ -639,7 +686,7 @@ def _solve_window_host(cnfs, live, packed, results, *, seed, steps, batch,
     assign0, mesh = _maybe_shard_window(assign0)
     kernels = _sat_kernels_mode()
     cap, chunk = _chunk_plan(steps, packed.n_clauses)
-    done = 0
+    done = segments = 0
     pending = set(range(K))
     # best-over-all-chunks near-miss per candidate (not final-chunk-only)
     nm_best = {j: (_INT32_MAX, None) for j in range(K)}
@@ -648,41 +695,47 @@ def _solve_window_host(cnfs, live, packed, results, *, seed, steps, batch,
             break
         key, kc = jax.random.split(key)
         keys = jax.random.split(kc, K)
-        solved, assign, tc = _run_chains_window(
-            packed.cvars, packed.csign, packed.ovars, packed.osign,
-            packed.n_vars, chunk, cb, assign0, keys, kernels, mesh)
-        solved_np = np.asarray(solved)
-        for j in sorted(pending):
-            i = live[j]
-            if should_skip is not None and should_skip(i):
-                pending.discard(j)
-                continue
-            if not solved_np[j].any():
-                continue
-            row = int(np.argmax(solved_np[j]))
-            model = [bool(b) for b in
-                     np.asarray(assign[j, row])[1:cnfs[i].n_vars + 1]]
-            _validate_model(cnfs[i], model, f"host engine, candidate {i}")
-            results[i] = (SAT, model)
-            pending.discard(j)
-            if on_sat is not None:
-                on_sat(i, model)
-        if (near_miss is not None or on_near_miss is not None) and pending:
-            n_unsat = np.asarray(jnp.sum(tc == 0, axis=-1))   # [K, B]
-            assign_np = None
+        with spans.span("walk.segment"):
+            solved, assign, tc = _run_chains_window(
+                packed.cvars, packed.csign, packed.ovars, packed.osign,
+                packed.n_vars, chunk, cb, assign0, keys, kernels, mesh)
+            solved_np = np.asarray(solved)
+        segments += 1
+        count(pending, done + chunk, segments)
+        with spans.span("walk.extract"):
             for j in sorted(pending):
-                row = int(np.argmin(n_unsat[j]))
-                if int(n_unsat[j, row]) < nm_best[j][0]:
-                    if assign_np is None:
-                        assign_np = np.asarray(assign)
-                    nm_best[j] = (int(n_unsat[j, row]),
-                                  assign_np[j, row].copy())
-                    if on_near_miss is not None:
-                        i = live[j]
-                        on_near_miss(
-                            i, nm_best[j][0],
-                            [bool(b) for b in
-                             nm_best[j][1][1:cnfs[i].n_vars + 1]])
+                i = live[j]
+                if should_skip is not None and should_skip(i):
+                    pending.discard(j)
+                    continue
+                if not solved_np[j].any():
+                    continue
+                row = int(np.argmax(solved_np[j]))
+                model = [bool(b) for b in
+                         np.asarray(assign[j, row])[1:cnfs[i].n_vars + 1]]
+                _validate_model(cnfs[i], model,
+                                f"host engine, candidate {i}")
+                results[i] = (SAT, model)
+                pending.discard(j)
+                if on_sat is not None:
+                    on_sat(i, model)
+            if (near_miss is not None or on_near_miss is not None) \
+                    and pending:
+                n_unsat = np.asarray(jnp.sum(tc == 0, axis=-1))  # [K, B]
+                assign_np = None
+                for j in sorted(pending):
+                    row = int(np.argmin(n_unsat[j]))
+                    if int(n_unsat[j, row]) < nm_best[j][0]:
+                        if assign_np is None:
+                            assign_np = np.asarray(assign)
+                        nm_best[j] = (int(n_unsat[j, row]),
+                                      assign_np[j, row].copy())
+                        if on_near_miss is not None:
+                            i = live[j]
+                            on_near_miss(
+                                i, nm_best[j][0],
+                                [bool(b) for b in
+                                 nm_best[j][1][1:cnfs[i].n_vars + 1]])
         assign0 = assign
         done += chunk
         chunk = _next_chunk(chunk, cap, steps - done)
@@ -707,6 +760,7 @@ def solve_walksat_window(cnfs: List[CNF], *, seed: int = 0,
                          engine: Optional[str] = None,
                          packed: Optional[PackedCNF] = None,
                          packs: Optional[List[Optional[HostPack]]] = None,
+                         walk_counts: Optional[dict] = None,
                          ) -> List[Tuple[str, Optional[List[bool]]]]:
     """Batched probSAT across a window of candidate-II CNFs.
 
@@ -742,6 +796,11 @@ def solve_walksat_window(cnfs: List[CNF], *, seed: int = 0,
     ``packs`` supplies per-CNF host packs for the stacker. Both come from
     the ``SolverSession`` pack cache — a warm sweep leg re-solving an
     unchanged window skips packing entirely.
+
+    ``walk_counts``, when given a dict, receives ``{i: WalkCounts}`` for
+    every candidate that walked, updated after each device segment while
+    the candidate is pending, so a caller reading it from another thread
+    sees the steps walked so far.
     """
     from . import SAT, UNKNOWN, UNSAT
     K = len(cnfs)
@@ -773,11 +832,20 @@ def solve_walksat_window(cnfs: List[CNF], *, seed: int = 0,
         packed = pack_cnf_window(
             [cnfs[i] for i in live],
             [packs[i] for i in live] if packs is not None else None)
+
+    def count(pending, steps_walked: int, segments: int) -> None:
+        if walk_counts is not None:
+            for j in pending:
+                i = live[j]
+                walk_counts[i] = WalkCounts(steps_walked, segments,
+                                            cnfs[i].n_clauses,
+                                            packed.n_clauses)
+
     run = _solve_window_device if engine == "device" else _solve_window_host
     return run(cnfs, live, packed, results, seed=seed, steps=steps,
                batch=batch, cb=cb, stop=stop, should_skip=should_skip,
                on_sat=on_sat, inits=inits, near_miss=near_miss,
-               on_near_miss=on_near_miss)
+               on_near_miss=on_near_miss, count=count)
 
 
 def solve_walksat(cnf: CNF, *, seed: int = 0, steps: int = 20000,
@@ -786,6 +854,7 @@ def solve_walksat(cnf: CNF, *, seed: int = 0, steps: int = 20000,
                   near_miss: Optional[dict] = None,
                   engine: Optional[str] = None,
                   pack: Optional[HostPack] = None,
+                  walk_counts: Optional[dict] = None,
                   ) -> Tuple[str, Optional[List[bool]]]:
     """Single-CNF probSAT: the K=1 window. Shares the window engines, the
     bucketed padded pack (consecutive IIs of a sweep — and the incremental
@@ -794,10 +863,12 @@ def solve_walksat(cnf: CNF, *, seed: int = 0, steps: int = 20000,
     chunk schedule, so a caller-provided ``steps`` is honoured exactly the
     same way in both entry points. ``near_miss`` receives ``{0: (n_unsat,
     assignment)}`` when the instance stays unsolved; ``pack`` supplies a
-    cached :func:`pack_cnf_np` of the CNF."""
+    cached :func:`pack_cnf_np` of the CNF; ``walk_counts`` receives
+    ``{0: WalkCounts}`` when the CNF walked."""
     res = solve_walksat_window(
         [cnf], seed=seed, steps=steps, batch=batch, cb=cb, stop=stop,
         inits=[init] if init is not None else None,
         near_miss=near_miss, engine=engine,
-        packs=[pack] if pack is not None else None)
+        packs=[pack] if pack is not None else None,
+        walk_counts=walk_counts)
     return res[0]

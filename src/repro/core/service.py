@@ -43,6 +43,7 @@ from copy import copy
 from dataclasses import astuple, dataclass, field
 from typing import Dict, Hashable, Optional, Tuple
 
+from . import spans
 from .cgra import CGRA
 from .dfg import DFG
 from .encode import EncoderSession
@@ -308,8 +309,14 @@ class MappingService:
         the warm-vs-cold comparison knob for benchmarks. The returned
         result carries a :class:`RequestStats` in ``.service``; cached
         results are shallow copies sharing placement/attempt objects, so
-        treat them as read-only.
+        treat them as read-only. The request's spans (``repro.core.spans``)
+        share one request id, opened here.
         """
+        with spans.span("service.map", request=True):
+            return self._map(dfg, cgra, cfg, sweep_width, use_cache)
+
+    def _map(self, dfg: DFG, cgra: CGRA, cfg: Optional[MapperConfig],
+             sweep_width: int, use_cache: bool) -> MappingResult:
         cfg = cfg or MapperConfig()
         t0 = time.time()
         key = self._cache_key(dfg, cgra, cfg, sweep_width)

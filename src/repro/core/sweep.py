@@ -46,6 +46,7 @@ from __future__ import annotations
 import time
 from typing import Dict, List, Optional, Tuple
 
+from . import spans
 from .cgra import CGRA
 from .dfg import DFG
 from .encode import EncoderSession, Encoding
@@ -172,24 +173,27 @@ def map_sweep(dfg: DFG, cgra: CGRA, cfg: Optional[MapperConfig] = None,
         stats_list: List[Dict[str, int]] = []
         for ii in iis:
             t0 = time.time()
-            if sess is not None:
-                sess.ensure_ii(ii)
-                stats_list.append(sess.stats_for(ii))
-            else:
-                encs.append(enc_session.encode(ii))
-                stats_list.append(encs[-1].stats)
+            with spans.span("map.encode"):
+                if sess is not None:
+                    sess.ensure_ii(ii)
+                    stats_list.append(sess.stats_for(ii))
+                else:
+                    encs.append(enc_session.encode(ii))
+                    stats_list.append(encs[-1].stats)
             enc_times.append(time.time() - t0)
         if sess is not None:
             # projections materialised only after the whole window is
             # encoded, so their variable space is window-consistent
-            cnfs = [sess.project(ii) for ii in iis]
+            with spans.span("map.encode"):
+                cnfs = [sess.project(ii) for ii in iis]
         else:
             cnfs = [e.cnf for e in encs]
 
         def decode(i: int, model: List[bool]):
-            if sess is not None:
-                return sess.enc.decode(iis[i], model)
-            return encs[i].decode(model)
+            with spans.span("map.decode"):
+                if sess is not None:
+                    return sess.enc.decode(iis[i], model)
+                return encs[i].decode(model)
 
         # regalloc results captured by the accept callback, keyed by window
         # index; accept returns True (=> cancel all higher IIs) only when
@@ -199,7 +203,8 @@ def map_sweep(dfg: DFG, cgra: CGRA, cfg: Optional[MapperConfig] = None,
 
         def accept(i: int, model: List[bool]) -> bool:
             placement = decode(i, model)
-            ra = allocate(dfg, cgra, placement, iis[i])
+            with spans.span("map.regalloc"):
+                ra = allocate(dfg, cgra, placement, iis[i])
             placements[i] = (placement, ra)
             return ra.ok
 
@@ -224,6 +229,10 @@ def map_sweep(dfg: DFG, cgra: CGRA, cfg: Optional[MapperConfig] = None,
                 att.evicted = r.stats.evicted
                 att.phase_hinted = r.stats.phase_hinted
                 att.racer_error = r.stats.racer_error
+                att.walk_steps = r.stats.walk_steps
+                att.walk_segments = r.stats.walk_segments
+                att.walk_rows = r.stats.walk_rows
+                att.walk_rows_padded = r.stats.walk_rows_padded
             if i in placements:
                 att.regalloc_ok = placements[i][1].ok
             res.attempts.append(att)
@@ -241,8 +250,9 @@ def map_sweep(dfg: DFG, cgra: CGRA, cfg: Optional[MapperConfig] = None,
 
         if winner is not None:
             placement, ra = placements[winner]
-            chk = verify_mapping(dfg, cgra, placement, iis[winner],
-                                 n_iters=cfg.verify_iters)
+            with spans.span("map.verify"):
+                chk = verify_mapping(dfg, cgra, placement, iis[winner],
+                                     n_iters=cfg.verify_iters)
             if not chk.ok:
                 raise AssertionError(
                     f"sweep produced an invalid mapping at II={iis[winner]}: "
