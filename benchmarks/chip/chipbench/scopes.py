@@ -17,8 +17,8 @@ not show those stats, so :func:`op_names` reads them from the file's
 protobuf wire format. Only leaf operations count: an event that encloses
 another on its line (a ``while`` and its body) is a container.
 
-``trace.read_xplane`` does not call this module yet: the benchmark's
-traced runs report no time per scope.
+``trace.read_xplane`` gives each device operation its scope the same
+way, and its ``scope_s`` is :func:`scope_seconds`'s.
 """
 from __future__ import annotations
 
@@ -59,7 +59,7 @@ def containers(ops: List[tuple]) -> set:
 
 def scope_seconds(device_ops: Dict[str, List[tuple]],
                   window: Tuple[float, float]) -> dict:
-    """``device_ops``: chip -> [(op name, start_ns, dur_ns, scope)];
+    """``device_ops``: chip -> [(op name, start_ns, dur_ns[, scope])];
     ``window``: the interval in ns. Over leaf operations only, summed
     over chips: their seconds (``leaf_s``) and the seconds of those that
     carry a scope, per scope (``scope_s``)."""
@@ -68,7 +68,8 @@ def scope_seconds(device_ops: Dict[str, List[tuple]],
     for chip in sorted(device_ops):
         ops = device_ops[chip]
         outer = containers(ops)
-        for j, (_, s, d, scope) in enumerate(ops):
+        for j, (_, s, d, *rest) in enumerate(ops):
+            scope = rest[0] if rest else None
             s, e = max(s, w0), min(s + d, w1)
             if j in outer or e <= s:
                 continue

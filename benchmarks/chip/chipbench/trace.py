@@ -1,18 +1,22 @@
 """Reduction of a profiler trace to the numbers the per-layer metrics
 read: device busy time (the union of operation intervals per chip), time
-per operation name, and the longest idle gaps named by the host span that
-was open in them.
+per operation name, time per named scope of the leaf operations, and the
+longest idle gaps named by the host span that was open in them.
 
 Input is the ``.xplane.pb`` that ``jax.profiler`` writes. Device planes
 are those named ``/device:TPU:<n>``; their operations are the events of
-the ``XLA Ops`` line (``XLA Modules`` where a plane has no op line). Host spans are the events of the ``/host:CPU``
-plane, where ``TraceAnnotation`` writes them on the same clock.
+the ``XLA Ops`` line (``XLA Modules`` where a plane has no op line), each
+with the scope of its HLO op_name (``scopes.py``). Host spans are the
+events of the ``/host:CPU`` plane, where ``TraceAnnotation`` writes them
+on the same clock.
 """
 from __future__ import annotations
 
 import glob
 import os
 from typing import Dict, List, Optional, Tuple
+
+from .scopes import op_names, scope_of, scope_seconds
 
 Interval = Tuple[float, float]
 
@@ -28,22 +32,23 @@ def union(intervals: List[Interval]) -> List[Interval]:
     return out
 
 
-def reduce_events(device_ops: Dict[str, List[Tuple[str, float, float]]],
+def reduce_events(device_ops: Dict[str, List[tuple]],
                   host_spans: List[Tuple[str, float, float]],
                   window: Interval) -> dict:
-    """``device_ops``: chip -> [(op name, start_ns, dur_ns)];
+    """``device_ops``: chip -> [(op name, start_ns, dur_ns[, scope])];
     ``host_spans``: [(span name, start_ns, dur_ns)]; ``window``: the traced
     interval in ns. Returns busy and window seconds (busy averaged over
-    chips), seconds per op name (summed over chips), and the ten longest
-    idle gaps of the first chip named by the innermost host span covering
-    each gap's midpoint."""
+    chips), seconds per op name (summed over chips), seconds per scope of
+    the leaf ops (``scopes.scope_seconds``; an op without one counts in
+    none), and the ten longest idle gaps of the first chip named by the
+    innermost host span covering each gap's midpoint."""
     w0, w1 = window
     busy = []
     by_name: Dict[str, float] = {}
     first_busy: List[Interval] = []
     for k, chip in enumerate(sorted(device_ops)):
         ivs = []
-        for name, s, d in device_ops[chip]:
+        for name, s, d, *_ in device_ops[chip]:
             s, e = max(s, w0), min(s + d, w1)
             if e <= s:
                 continue
@@ -69,6 +74,7 @@ def reduce_events(device_ops: Dict[str, List[Tuple[str, float, float]]],
     return {"busy_s": sum(busy) / len(busy) if busy else 0.0,
             "window_s": (w1 - w0) / 1e9,
             "op_s": by_name,
+            "scope_s": scope_seconds(device_ops, window)["scope_s"],
             "idle_gaps": named}
 
 
@@ -83,8 +89,11 @@ def read_xplane(trace_dir: str, window_span: str = "benchmark.window",
                       recursive=True)
     if not paths:
         raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
-    pd = ProfileData.from_file(max(paths, key=os.path.getmtime))
-    device_ops: Dict[str, List[Tuple[str, float, float]]] = {}
+    with open(max(paths, key=os.path.getmtime), "rb") as fh:
+        data = fh.read()
+    names = op_names(data)
+    pd = ProfileData.from_serialized_xspace(data)
+    device_ops: Dict[str, List[tuple]] = {}
     host: List[Tuple[str, float, float]] = []
     lo, hi = float("inf"), float("-inf")
     window: Optional[Interval] = None
@@ -95,9 +104,11 @@ def read_xplane(trace_dir: str, window_span: str = "benchmark.window",
             ops = device_ops.setdefault(plane.name, [])
             lines = {line.name: line for line in plane.lines}
             line = lines.get("XLA Ops", lines.get("XLA Modules"))
+            op_name = names.get(plane.name, {})
             if line is not None:
                 for ev in line.events:
-                    ops.append((ev.name, ev.start_ns, ev.duration_ns))
+                    ops.append((ev.name, ev.start_ns, ev.duration_ns,
+                                scope_of(op_name.get(ev.name, ""))))
                     lo = min(lo, ev.start_ns)
                     hi = max(hi, ev.start_ns + ev.duration_ns)
         elif plane.name == "/host:CPU":

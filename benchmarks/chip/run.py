@@ -22,7 +22,8 @@ and a traffic mix (``traffic/<name>.json``). A run
    number beside its limit as the last lines of standard error, and one
    JSON object as the last line of standard output.
 
-``--trace 1`` records a profiler trace of the window and reports the
+``--trace 1`` records a profiler trace of the window, with the program's
+host spans (``repro.core.spans``) switched on for it, and reports the
 cell's per-layer metrics (``metrics/<name>.py``) instead of the
 end-to-end ones. The run owns every chip of the host and exits non-zero,
 printing no result, where JAX finds no TPU or fewer chips than the cell
@@ -398,6 +399,7 @@ def fabrics_of(config: dict, mix: dict) -> dict:
 
 
 def _measure(args, spec, pool, require_chip, jax, device, probes):
+    from repro.core import spans
     from repro.core.mapper import MapperConfig
     from repro.core.sat import portfolio
     from repro.core.workers import WorkerPool
@@ -445,6 +447,7 @@ def _measure(args, spec, pool, require_chip, jax, device, probes):
                         opts.python_tracer_level = 0
                         jax.profiler.start_trace(trace_dir,
                                                  profiler_options=opts)
+                        spans.enable()      # empties its buffer
                     try:
                         out = await drive(door, fabrics, cfg, width, items,
                                           mix["arrival"], times,
@@ -453,15 +456,18 @@ def _measure(args, spec, pool, require_chip, jax, device, probes):
                         gc.unfreeze()
                         if args.trace:
                             jax.profiler.stop_trace()
+                            spans.enable(False)
+                    recorded = spans.drain()
                     peak = max((d.memory_stats() or {}).get(
                         "peak_bytes_in_use", 0) for d in jax.devices())
-                    return out, setup_s, trace_dir, peak, wpool.stats()
+                    return out, setup_s, trace_dir, peak, wpool.stats(), \
+                        recorded
             finally:
                 probes.uninstall()
                 portfolio._reset_pool()     # the CDCL workers, if it forked
 
-    (records, t_start, t_end, t_close), setup_s, trace_dir, peak, stats = \
-        asyncio.run(serve())
+    (records, t_start, t_end, t_close), setup_s, trace_dir, peak, stats, \
+        recorded = asyncio.run(serve())
     device["memory_peak_bytes"] = int(peak)
 
     # ---- after the window: trace, metrics, reference
@@ -475,6 +481,7 @@ def _measure(args, spec, pool, require_chip, jax, device, probes):
         device["busy_s"] = red["busy_s"]
         device["window_s"] = red["window_s"]
         say(f"[trace] planes={json.dumps(red['planes'])}")
+        say(f"[spans] recorded={len(recorded)} dropped={spans.dropped()}")
     window = [r for r in records if r["t0"] <= t_end]
     result = judge(window, fabrics, args.seed, pool)
     say(f"[counters] {json.dumps(result['counters'])} "
@@ -497,7 +504,8 @@ def _measure(args, spec, pool, require_chip, jax, device, probes):
             say(f"[detail] {k}: {v}")
 
     if args.trace:
-        ctx = Context(window, t_start, t_end, probes, red, device, spec)
+        ctx = Context(window, t_start, t_end, probes, red, device, spec,
+                      recorded)
         metrics = {}
         for m in spec["per_layer"]:
             val = load_file("metrics", m["name"]).read(ctx)
@@ -526,7 +534,8 @@ def _measure(args, spec, pool, require_chip, jax, device, probes):
 class Context:
     """What a per-layer metric reader sees of one traced run."""
 
-    def __init__(self, records, t_start, t_end, probes, trace, device, spec):
+    def __init__(self, records, t_start, t_end, probes, trace, device, spec,
+                 spans):
         self.records = records
         self.served = [r for r in records if "res" in r]
         self.t_start, self.t_end = t_start, t_end
@@ -534,6 +543,7 @@ class Context:
         self.probes = probes
         self.segments = probes.segments_between(t_start, t_end)
         self.trace = trace
+        self.spans = list(spans)    # the program's, recorded in the window
         self.device = device
         self.spec = spec
 

@@ -62,6 +62,19 @@ def test_walk_mix_is_one_set_that_the_seed_orders():
         assert reference.kms_feasible(r.graph, FAB, r.ii) is True
 
 
+def test_walk_mix_offers_288_and_keeps_the_first_96_in_order():
+    """Raising ``requests`` from 96 only lengthens the list: the loops a
+    window reached at 96 come first, name for name and in order."""
+    walk = traffic.load_mix("walk")
+    data = json.loads((HERE / "data" / "walk_loops.json").read_text())
+    assert walk["requests"] == 288 <= len(data["loops"]) == 480
+    now = traffic.requests(walk, 2**31 + 13)
+    assert len(now) == 288
+    before = traffic.requests(dict(walk, requests=96), 2**31 + 13)
+    assert [r.name for r in now[:96]] == [r.name for r in before]
+    assert now[:96] == before
+
+
 def test_open_loop_arrivals_are_seeded_bursts():
     mix = traffic.check_mix({"source": "suite_mutants", "requests": 10,
                              "arrival": {"kind": "open", "rate_per_s": 20,
