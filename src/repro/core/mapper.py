@@ -98,13 +98,15 @@ class IIAttempt:
     racer_error: Optional[str] = None
     # the device walk for this II (SolveStats.walk_*): probSAT steps and
     # segments walked while the II was pending (the IIs of one sweep
-    # window share one walk), its real clause rows and the padded rows of
-    # the walked pack; None where no walk ran. Results pickled before
+    # window share one walk), its real clause rows, the padded rows of
+    # the walked pack and the steps whose pick read the break cache (equal
+    # to walk_steps); None where no walk ran. Results pickled before
     # these fields existed lack them: read with getattr(att, name, None)
     walk_steps: Optional[int] = None
     walk_segments: Optional[int] = None
     walk_rows: Optional[int] = None
     walk_rows_padded: Optional[int] = None
+    walk_break_cached: Optional[int] = None
 
 
 @dataclass
@@ -175,7 +177,8 @@ def _try_ii(dfg: DFG, cgra: CGRA, ii: int, cfg: MapperConfig,
                         walk_steps=stats.walk_steps,
                         walk_segments=stats.walk_segments,
                         walk_rows=stats.walk_rows,
-                        walk_rows_padded=stats.walk_rows_padded)
+                        walk_rows_padded=stats.walk_rows_padded,
+                        walk_break_cached=stats.walk_break_cached)
         attempts.append(att)
         if status != SAT:
             return None

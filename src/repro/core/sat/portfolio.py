@@ -222,17 +222,19 @@ class SolveStats:
     # window (solve_window reports it on the window's first candidate)
     racer_error: Optional[str] = None
     # the device walk for this candidate (walksat_jax.WalkCounts): steps
-    # and segments walked while it was pending, its real clause rows and
-    # the padded rows of the walked pack (None = it did not walk)
+    # and segments walked while it was pending, its real clause rows, the
+    # padded rows of the walked pack and the steps whose pick read the
+    # break cache (None = it did not walk)
     walk_steps: Optional[int] = None
     walk_segments: Optional[int] = None
     walk_rows: Optional[int] = None
     walk_rows_padded: Optional[int] = None
+    walk_break_cached: Optional[int] = None
 
     def note_walk(self, counts) -> None:
         if counts is not None:
             (self.walk_steps, self.walk_segments, self.walk_rows,
-             self.walk_rows_padded) = counts
+             self.walk_rows_padded, self.walk_break_cached) = counts
 
 
 class SolverSession:

@@ -22,18 +22,33 @@ Two engines drive the chunked walk:
     engine) and selectable via ``REPRO_WALKSAT_ENGINE=host``.
 
 Both engines share one inner step (``_pick_flip_one`` + the flip/true-count
-update), so they consume the PRNG stream identically and return identical
-results for a fixed seed. On TPU/GPU the true-count evaluation routes
-through the ``kernels/clause_eval`` Pallas kernel and the flip+incremental
-true-count update through the fused ``kernels/flip_update`` kernel
-(``REPRO_SAT_KERNELS`` overrides: ``0`` forces the pure-jnp path, ``interpret``
-forces the kernels in interpret mode — the CPU-testable route).
+update + the break-cache update), so they consume the PRNG stream
+identically and return identical results for a fixed seed.
+
+The pick reads probSAT's incremental break cache rather than recomputing
+break counts: each chain carries ``tsum [C]``, the sum of the variable ids
+of each clause's true literals (where a clause has exactly one true
+literal, its critical variable), and ``brk [V+1]``, the number of clauses
+each variable is critical in. A pick reads ``brk`` at the picked clause's
+L literals; a flip of v moves ``brk`` from v's O occurrences
+(``_break_update_one``) and ``tsum`` by v times each clause's change of
+true count. Both are built once per walk from the start assignment
+(``_walk_start``), ``brk`` with :func:`break_counts_ref`, the recomputing
+formula, which is also the tests' oracle for the cache.
+
+On TPU/GPU the true-count evaluation routes through the
+``kernels/clause_eval`` Pallas kernel and the flip+incremental true-count
+update through the fused ``kernels/flip_update`` kernel
+(``REPRO_SAT_KERNELS`` overrides: ``0`` forces the pure-jnp path,
+``interpret`` forces the kernels in interpret mode — the CPU-testable
+route).
 
 This solver is incomplete: it can certify SAT but returns UNKNOWN instead of
 UNSAT — the Fig. 3 loop then falls back to CDCL/Z3 for the UNSAT proof.
 
 ``pack_cnf``/``true_counts_ref`` are also the reference oracle for the
-``kernels/clause_eval`` Pallas kernel.
+``kernels/clause_eval`` Pallas kernel; ``break_counts_ref`` is the oracle
+for the break cache.
 """
 from __future__ import annotations
 
@@ -81,12 +96,14 @@ def _validate_model(cnf: CNF, model: List[bool], ctx: str) -> None:
 class WalkCounts(NamedTuple):
     """What one candidate's walk cost: probSAT steps and device segments
     walked while it was pending (candidates of one window walk together,
-    so they share these), its real clause rows and the window's padded
-    clause rows C."""
+    so they share these), its real clause rows, the window's padded
+    clause rows C, and the steps whose pick read the break cache (counted
+    on the device; equal to ``steps``)."""
     steps: int
     segments: int
     rows: int
     rows_padded: int
+    break_cached: int
 
 
 class PackedCNF(NamedTuple):
@@ -185,6 +202,62 @@ def true_counts_batch(packed: PackedCNF, assign: jnp.ndarray,
     return jax.vmap(lambda a: true_counts_ref(packed, a))(assign)
 
 
+def break_counts_ref(ovars: jnp.ndarray, osign: jnp.ndarray,
+                     assign: jnp.ndarray, tc: jnp.ndarray) -> jnp.ndarray:
+    """Break count of every variable: the clauses in which it is the sole
+    true literal. ovars/osign [V+1, O]; assign [B, V+1] bool; tc [B, C]
+    int32 -> [B, V+1] int32.
+
+    Pure-jnp oracle of the walk's break cache, and its initial value.
+    Built from the occurrence lists, so the tautology rows that
+    ``pack_cnf_window`` pads with count for no variable."""
+    valid = ovars >= 0
+    tc_at = tc[:, jnp.where(valid, ovars, 0)]            # [B, V+1, O]
+    supports = osign[None] == assign[:, :, None]       # v satisfies c
+    return jnp.sum(valid[None] & supports & (tc_at == 1), axis=-1,
+                   dtype=jnp.int32)
+
+
+# occurrence slots per scatter of the sums' build: XLA:TPU's compile time
+# for one scatter grows steeply with its index count (for a v5e, 23 s at
+# the largest suite window's 213,200 slots, 0.3 s at 6,656), so the build
+# loops over blocks of variables instead
+_SUMS_BLOCK_SLOTS = 4096
+
+
+def _true_var_sums(ovars: jnp.ndarray, osign: jnp.ndarray,
+                   assign: jnp.ndarray, n_clauses: int) -> jnp.ndarray:
+    """Per clause, the sum of the variable ids of its true literals, from
+    the occurrence lists of a window: ovars/osign [K, V+1, O], assign
+    [K, B, V+1] -> [K, B, C] int32 (0 on padding rows). A loop over blocks
+    of variables scatters each block's chain-wide rows over the K·C
+    clauses (a vmapped scatter would also lose its named scope in the TPU
+    compiler's rewrite)."""
+    k, v1, o = ovars.shape
+    b = assign.shape[1]
+    vb = max(1, min(v1, _SUMS_BLOCK_SLOTS // (k * o)))
+    pad = -v1 % vb
+    ovars = jnp.pad(ovars, ((0, 0), (0, pad), (0, 0)), constant_values=-1)
+    osign = jnp.pad(osign, ((0, 0), (0, pad), (0, 0)))
+    assign = jnp.pad(assign, ((0, 0), (0, 0), (0, pad)))
+    base = (jnp.arange(k, dtype=jnp.int32) * n_clauses)[:, None, None]
+
+    def block(j, t):
+        ov = jax.lax.dynamic_slice_in_dim(ovars, j * vb, vb, axis=1)
+        os_ = jax.lax.dynamic_slice_in_dim(osign, j * vb, vb, axis=1)
+        a = jax.lax.dynamic_slice_in_dim(assign, j * vb, vb, axis=2)
+        valid = ov >= 0
+        supports = valid[:, None] & (os_[:, None] == a[..., None])
+        ids = (j * vb + jnp.arange(vb, dtype=jnp.int32))[None, None, :, None]
+        upd = jnp.where(supports, ids, 0)              # [K, B, vb, O]
+        rows = (base + jnp.where(valid, ov, 0)).reshape(-1)
+        return t.at[rows].add(jnp.moveaxis(upd, 1, 3).reshape(-1, b))
+
+    t = jax.lax.fori_loop(0, (v1 + pad) // vb, block,
+                          jnp.zeros((k * n_clauses, b), jnp.int32))
+    return jnp.swapaxes(t.reshape(k, n_clauses, b), 1, 2)
+
+
 # ----------------------------------------------------------- kernel routing
 
 def _sat_kernels_mode() -> Optional[str]:
@@ -258,11 +331,14 @@ def _window_tc(cvars: jnp.ndarray, csign: jnp.ndarray, assign: jnp.ndarray,
 
 # ------------------------------------------------------------ probSAT step
 
-def _pick_flip_one(cvars, ovars, osign, assign, tc, key, cb):
+def _pick_flip_one(cvars, brk, assign, tc, key, cb):
     """One probSAT variable pick for a batch of chains of one CNF.
 
-    assign: [B, V+1] bool, tc: [B, C] int32. Returns (v_flip [B] — var 0
-    (the dummy) for already-solved chains, new_val [B], key')."""
+    assign: [B, V+1] bool, tc: [B, C] int32, brk: [B, V+1] int32 — the
+    break cache, each variable's count of clauses it is the sole true
+    literal of (:func:`break_counts_ref` recomputes it). The pick reads
+    the cache at the picked clause's Lmax literals. Returns (v_flip [B] —
+    var 0 (the dummy) for already-solved chains, new_val [B], key')."""
     with jax.named_scope("walk.pick.clause"):
         unsat = tc == 0                       # [B, C]
         any_unsat = jnp.any(unsat, axis=-1)   # [B]
@@ -273,16 +349,11 @@ def _pick_flip_one(cvars, ovars, osign, assign, tc, key, cb):
     with jax.named_scope("walk.pick.break"):
         vs = cvars[cidx]                      # [B, Lmax]
         vmask = vs > 0
-        # break count per candidate var: clauses where v is the sole support
-        occ_c = ovars[vs]                     # [B, Lmax, Omax]
-        occ_s = osign[vs]
-        occ_valid = occ_c >= 0
-        occ_cc = jnp.where(occ_valid, occ_c, 0)
-        flat = occ_cc.reshape(occ_cc.shape[0], -1)          # [B, L*O]
-        tc_at = jnp.take_along_axis(tc, flat, axis=-1).reshape(occ_c.shape)
-        a_at = jnp.take_along_axis(assign, vs, axis=-1)     # [B, Lmax]
-        supports = occ_s == a_at[..., None]   # var currently satisfies c'
-        brk = jnp.sum(occ_valid & supports & (tc_at == 1), axis=-1)
+        # the cache at the clause's literals, as a one-hot read over the
+        # variables (a TPU gathers index by index)
+        ids = jnp.arange(brk.shape[-1], dtype=jnp.int32)
+        brk = jnp.sum(jnp.where(vs[..., None] == ids, brk[:, None, :], 0),
+                      axis=-1)                # [B, Lmax]
         # probSAT polynomial heuristic: p ∝ (1 + brk)^-cb
         w = jnp.where(vmask, -cb * jnp.log1p(brk.astype(jnp.float32)),
                       -1e30)
@@ -308,28 +379,60 @@ def _apply_flip_one(ovars, osign, assign, tc, v_flip, new_val):
     return assign, tc
 
 
-def _window_chunk(cvars, csign, ovars, osign, assign, tc, keys, n_steps, cb,
-                  kernels: Optional[str], mesh=None):
+def _break_update_one(brk, tsum, tc, occ, osg, v, new_val):
+    """Break counts of one chain after a flip of variable ``v``.
+
+    brk [V+1]; tsum [C], tc [C] — the sums and true counts before the
+    flip; occ/osg [O] — v's occurrence row (clause ids, -1 = padding;
+    literal signs). Each distinct clause of the row moves by its net
+    change of true literals (a clause that holds v twice moves by the sum
+    of both): its old critical variable, if it had one, loses the clause
+    and its new one gains it. Padding slots, and the dummy variable 0 that
+    solved chains flip, touch nothing. The 2·O changes land by a one-hot
+    sum over the variables, not a scatter. Returns brk'."""
+    valid = occ >= 0
+    oc = jnp.where(valid, occ, 0)
+    delta = jnp.where(valid, jnp.where(osg == new_val, 1, -1), 0)
+    same = (oc[:, None] == oc[None, :]) & valid[:, None] & valid[None, :]
+    d = jnp.sum(jnp.where(same, delta[None, :], 0), axis=-1)   # net change
+    o = jnp.arange(oc.shape[0])
+    first = valid & ~jnp.any(same & (o[None, :] < o[:, None]), axis=-1)
+    t0, s0 = tc[oc], tsum[oc]
+    idx = jnp.concatenate([s0, s0 + d * v])   # old, new critical variable
+    upd = jnp.concatenate([jnp.where(first & (t0 == 1), -1, 0),
+                           jnp.where(first & (t0 + d == 1), 1, 0)])
+    ids = jnp.arange(brk.shape[0], dtype=jnp.int32)
+    return brk + jnp.sum(jnp.where(idx[:, None] == ids, upd[:, None], 0),
+                         axis=0, dtype=jnp.int32)
+
+
+def _window_chunk(cvars, csign, ovars, osign, assign, tc, tsum, brk, keys,
+                  n_steps, cb, kernels: Optional[str], mesh=None):
     """Walk all K CNFs for ``n_steps`` probSAT steps (n_steps may be a
     traced scalar — both engines share this one implementation, so they
     consume the PRNG stream identically and stay bit-compatible).
 
-    assign: [K, B, V+1] bool; tc: [K, B, C] int32; keys: [K, 2].
+    assign: [K, B, V+1] bool; tc, tsum: [K, B, C] int32; brk: [K, B, V+1]
+    int32; keys: [K, 2]. Returns (assign, tc, tsum, brk, keys, picks):
+    ``picks`` counts the steps whose pick read the break cache.
     """
     del csign  # only the pick/update tensors are read here
 
     def body(_, carry):
-        assign, tc, keys = carry
+        assign, tc, tsum, brk, keys, picks = carry
         v_flip, new_val, keys = jax.vmap(
-            lambda cv, ov, os_, a, t, k:
-            _pick_flip_one(cv, ov, os_, a, t, k, cb)
-        )(cvars, ovars, osign, assign, tc, keys)
+            lambda cv, b, a, t, k: _pick_flip_one(cv, b, a, t, k, cb)
+        )(cvars, brk, assign, tc, keys)
         with jax.named_scope("walk.flip"):
+            kk = jnp.arange(assign.shape[0])[:, None]
+            occ_c = ovars[kk, v_flip]          # [K, B, O]
+            occ_s = osign[kk, v_flip]
+            with jax.named_scope("walk.pick.break"):
+                brk = jax.vmap(jax.vmap(_break_update_one))(
+                    brk, tsum, tc, occ_c, occ_s, v_flip, new_val)
+            tc_old = tc
             if kernels is not None:
                 from ...kernels.flip_update import flip_update
-                kk = jnp.arange(assign.shape[0])[:, None]
-                occ_c = ovars[kk, v_flip]      # [K, B, O]
-                occ_s = osign[kk, v_flip]
                 interpret = True if kernels == "interpret" else None
                 assign, tc = _batch_sharded(
                     lambda *a: flip_update(*a, interpret=interpret),
@@ -338,9 +441,28 @@ def _window_chunk(cvars, csign, ovars, osign, assign, tc, keys, n_steps, cb,
             else:
                 assign, tc = jax.vmap(_apply_flip_one)(
                     ovars, osign, assign, tc, v_flip, new_val)
-        return assign, tc, keys
+            with jax.named_scope("walk.pick.break"):
+                # a clause's sum moves by v for each true literal of v it
+                # gains or loses: one dense pass, no scatter
+                tsum = tsum + v_flip[..., None] * (tc - tc_old)
+        return assign, tc, tsum, brk, keys, picks + 1
 
-    return jax.lax.fori_loop(0, n_steps, body, (assign, tc, keys))
+    return jax.lax.fori_loop(0, n_steps, body,
+                             (assign, tc, tsum, brk, keys, jnp.int32(0)))
+
+
+@functools.partial(jax.jit, static_argnums=(5, 6))
+def _walk_start(cvars, csign, ovars, osign, assign0, kernels: Optional[str],
+                mesh=None):
+    """The walk state a start assignment implies: (tc [K,B,C], tsum
+    [K,B,C], brk [K,B,V+1]). The true counts are scoped ``walk.init``;
+    the break cache's build is ``walk.pick.break``, the pick's cost."""
+    with jax.named_scope("walk.init"):
+        tc0 = _window_tc(cvars, csign, assign0, kernels, mesh)
+    with jax.named_scope("walk.pick.break"):
+        tsum0 = _true_var_sums(ovars, osign, assign0, cvars.shape[1])
+        brk0 = jax.vmap(break_counts_ref)(ovars, osign, assign0, tc0)
+    return tc0, tsum0, brk0
 
 
 @functools.partial(jax.jit, static_argnums=(4, 5, 6, 9, 10))
@@ -355,17 +477,18 @@ def _run_chains_window(cvars: jnp.ndarray, csign: jnp.ndarray,
 
     cvars/csign: [K, C, Lmax]; ovars/osign: [K, V+1, Omax];
     assign0: [K, B, V+1]; keys: [K, 2]. Returns (solved [K, B], assign,
-    per-clause true counts [K, B, C] — the near-miss signal).
+    per-clause true counts [K, B, C] — the near-miss signal —, the steps
+    whose pick read the break cache).
     """
     del n_vars
-    with jax.named_scope("walk.init"):
-        tc0 = _window_tc(cvars, csign, assign0, kernels, mesh)
-    assign, tc, _ = _window_chunk(cvars, csign, ovars, osign,
-                                  assign0, tc0, keys, steps, cb, kernels,
-                                  mesh)
+    tc0, tsum0, brk0 = _walk_start(cvars, csign, ovars, osign, assign0,
+                                   kernels, mesh)
+    assign, tc, _, _, _, picks = _window_chunk(
+        cvars, csign, ovars, osign, assign0, tc0, tsum0, brk0, keys, steps,
+        cb, kernels, mesh)
     with jax.named_scope("walk.chunk_end"):
         solved = ~jnp.any(tc == 0, axis=-1)
-    return solved, assign, tc
+    return solved, assign, tc, picks
 
 
 # -------------------------------------------------------- chunk scheduling
@@ -522,9 +645,11 @@ def _device_segment(poll_chunks: int, cb: float, kernels: Optional[str],
     first chain observed solved, snapshotted in the chunk it solved so a
     late poll returns the same model the per-chunk host engine would have,
     skip [K], best_unsat [K], best_assign [K,V+1] — best-over-all-chunks
-    near-miss state, tracked only while a candidate is still pending).
-    Only ``solved``/``done`` need to reach the host between segments; the
-    big buffers stay device-resident for the next segment.
+    near-miss state, tracked only while a candidate is still pending —,
+    tsum [K,B,C] and brk [K,B,V+1] — the break cache, see
+    ``_break_update_one`` —, picks — the steps whose pick read the cache).
+    Only ``solved``/``done``/``picks`` need to reach the host between
+    segments; the big buffers stay device-resident for the next segment.
     """
     K = state[0].shape[0]
 
@@ -532,20 +657,20 @@ def _device_segment(poll_chunks: int, cb: float, kernels: Optional[str],
     # snapshots, schedule) is scoped walk.chunk_end; the steps carry
     # their own walk.pick.* / walk.flip scopes
     def cond(st):
-        _, _, _, done, _, solved, _, skip, _, _, polls = st
+        _, _, _, done, _, solved, _, skip, _, _, _, _, _, polls = st
         with jax.named_scope("walk.chunk_end"):
             return ((done < steps) & jnp.any(~(solved | skip))
                     & (polls < poll_chunks))
 
     def body(st):
         (assign, tc, key, done, chunk, solved, solved_assign, skip,
-         best_unsat, best_assign, polls) = st
+         best_unsat, best_assign, tsum, brk, picks, polls) = st
         with jax.named_scope("walk.chunk_end"):
             key, kc = jax.random.split(key)
             keys = jax.random.split(kc, K)
-        assign, tc, _ = _window_chunk(cvars, csign, ovars, osign,
-                                      assign, tc, keys, chunk, cb, kernels,
-                                      mesh)
+        assign, tc, tsum, brk, _, n = _window_chunk(
+            cvars, csign, ovars, osign, assign, tc, tsum, brk, keys, chunk,
+            cb, kernels, mesh)
         with jax.named_scope("walk.chunk_end"):
             chain_ok = ~jnp.any(tc == 0, axis=-1)       # [K, B]
             cand_ok = jnp.any(chain_ok, axis=-1)        # [K]
@@ -566,7 +691,7 @@ def _device_segment(poll_chunks: int, cb: float, kernels: Optional[str],
             done = done + chunk
             chunk = _next_chunk_jnp(chunk, cap, steps - done)
         return (assign, tc, key, done, chunk, solved, solved_assign, skip,
-                best_unsat, best_assign, polls + 1)
+                best_unsat, best_assign, tsum, brk, picks + n, polls + 1)
 
     out = jax.lax.while_loop(cond, body, state + (jnp.int32(0),))
     return out[:-1]
@@ -580,9 +705,9 @@ def _solve_window_device(cnfs, live, packed, results, *, seed, steps, batch,
     key = jax.random.PRNGKey(seed)
     key, k0 = jax.random.split(key)
     init_keys = jax.random.split(k0, K)
-    # the walk's start runs eagerly, as small programs of its own that
-    # carry no walk.* scope (the host engine's jitted chunk scopes its
-    # true counts walk.init)
+    # the start assignments are small eager programs that carry no walk.*
+    # scope; the true counts and the break cache they imply are one jitted
+    # program, scoped as the host engine's chunk scopes them
     assign0 = jnp.stack([
         _init_assign(init_keys[j], batch, packed.n_vars,
                      inits[live[j]] if inits is not None else None)
@@ -590,13 +715,15 @@ def _solve_window_device(cnfs, live, packed, results, *, seed, steps, batch,
     assign0, mesh = _maybe_shard_window(assign0)
     kernels = _sat_kernels_mode()
     cap, chunk0 = _chunk_plan(steps, packed.n_clauses)
-    tc0 = _window_tc(packed.cvars, packed.csign, assign0, kernels, mesh)
+    tc0, tsum0, brk0 = _walk_start(packed.cvars, packed.csign, packed.ovars,
+                                   packed.osign, assign0, kernels, mesh)
     v1 = packed.n_vars + 1
     state = (assign0, tc0, key,
              jnp.int32(0), jnp.int32(chunk0),
              jnp.zeros(K, bool), jnp.zeros((K, v1), bool),
              jnp.zeros(K, bool),
-             jnp.full(K, _INT32_MAX, jnp.int32), jnp.zeros((K, v1), bool))
+             jnp.full(K, _INT32_MAX, jnp.int32), jnp.zeros((K, v1), bool),
+             tsum0, brk0, jnp.int32(0))
     skip_host = np.zeros(K, bool)
     pending = set(range(K))
     nm_emitted = np.full(K, _INT32_MAX, np.int64)   # last streamed quality
@@ -618,14 +745,15 @@ def _solve_window_device(cnfs, live, packed, results, *, seed, steps, batch,
                                     packed.cvars, packed.csign,
                                     packed.ovars, packed.osign,
                                     jnp.int32(steps), jnp.int32(cap), state)
-            # the host blocks only on the tiny status pair; the walk state
-            # (assignments, true counts, near-miss buffers) stays on device
-            solved_dev, done_dev = jax.block_until_ready((state[5],
-                                                          state[3]))
+            # the host blocks only on the tiny status; the walk state
+            # (assignments, true counts, break cache, near-miss buffers)
+            # stays on device
+            solved_dev, done_dev, picks_dev = jax.block_until_ready(
+                (state[5], state[3], state[12]))
         solved_np = np.asarray(solved_dev)
         done = int(done_dev)
         segments += 1
-        count(pending, done, segments)
+        count(pending, done, segments, int(picks_dev))
         with spans.span("walk.extract"):
             for j in sorted(pending):
                 if not solved_np[j]:
@@ -686,7 +814,7 @@ def _solve_window_host(cnfs, live, packed, results, *, seed, steps, batch,
     assign0, mesh = _maybe_shard_window(assign0)
     kernels = _sat_kernels_mode()
     cap, chunk = _chunk_plan(steps, packed.n_clauses)
-    done = segments = 0
+    done = segments = picks = 0
     pending = set(range(K))
     # best-over-all-chunks near-miss per candidate (not final-chunk-only)
     nm_best = {j: (_INT32_MAX, None) for j in range(K)}
@@ -696,12 +824,13 @@ def _solve_window_host(cnfs, live, packed, results, *, seed, steps, batch,
         key, kc = jax.random.split(key)
         keys = jax.random.split(kc, K)
         with spans.span("walk.segment"):
-            solved, assign, tc = _run_chains_window(
+            solved, assign, tc, n = _run_chains_window(
                 packed.cvars, packed.csign, packed.ovars, packed.osign,
                 packed.n_vars, chunk, cb, assign0, keys, kernels, mesh)
             solved_np = np.asarray(solved)
         segments += 1
-        count(pending, done + chunk, segments)
+        picks += int(n)
+        count(pending, done + chunk, segments, picks)
         with spans.span("walk.extract"):
             for j in sorted(pending):
                 i = live[j]
@@ -833,13 +962,14 @@ def solve_walksat_window(cnfs: List[CNF], *, seed: int = 0,
             [cnfs[i] for i in live],
             [packs[i] for i in live] if packs is not None else None)
 
-    def count(pending, steps_walked: int, segments: int) -> None:
+    def count(pending, steps_walked: int, segments: int,
+              picks: int) -> None:
         if walk_counts is not None:
             for j in pending:
                 i = live[j]
                 walk_counts[i] = WalkCounts(steps_walked, segments,
                                             cnfs[i].n_clauses,
-                                            packed.n_clauses)
+                                            packed.n_clauses, picks)
 
     run = _solve_window_device if engine == "device" else _solve_window_host
     return run(cnfs, live, packed, results, seed=seed, steps=steps,

@@ -233,6 +233,7 @@ def map_sweep(dfg: DFG, cgra: CGRA, cfg: Optional[MapperConfig] = None,
                 att.walk_segments = r.stats.walk_segments
                 att.walk_rows = r.stats.walk_rows
                 att.walk_rows_padded = r.stats.walk_rows_padded
+                att.walk_break_cached = r.stats.walk_break_cached
             if i in placements:
                 att.regalloc_ok = placements[i][1].ok
             res.attempts.append(att)

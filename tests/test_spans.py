@@ -170,17 +170,19 @@ def test_a_served_request_names_its_layers(recording):
     for a in walked:
         assert 0 < a.walk_rows <= a.walk_rows_padded
         assert a.walk_rows_padded % 1024 == 0
+        assert a.walk_break_cached == a.walk_steps
 
 
 def test_attempts_pickled_without_walk_counters_still_load():
     att = IIAttempt(ii=3, n_vars=10, n_clauses=20, status="SAT",
                     solve_time=0.1, encode_time=0.01)
     for name in ("walk_steps", "walk_segments", "walk_rows",
-                 "walk_rows_padded"):
+                 "walk_rows_padded", "walk_break_cached"):
         del att.__dict__[name]        # as an attempt of an older program
     old = pickle.loads(pickle.dumps(att))
     assert old.walk_steps is None and old.walk_rows_padded is None
     assert getattr(old, "walk_rows", None) is None
+    assert getattr(old, "walk_break_cached", None) is None
 
 
 def test_the_span_module_imports_no_jax():

@@ -71,7 +71,9 @@ def _segment_args(name, rep, bat):
              _spec((2,), jnp.uint32, rep), _spec((), jnp.int32, rep),
              _spec((), jnp.int32, rep), _spec((K,), jnp.bool_, rep),
              _spec((K, v1), jnp.bool_, rep), _spec((K,), jnp.bool_, rep),
-             _spec((K,), jnp.int32, rep), _spec((K, v1), jnp.bool_, rep))
+             _spec((K,), jnp.int32, rep), _spec((K, v1), jnp.bool_, rep),
+             _spec((K, B, C), jnp.int32, bat),
+             _spec((K, B, v1), jnp.int32, bat), _spec((), jnp.int32, rep))
     return (_spec((K, C, L), jnp.int32, rep), _spec((K, C, L), jnp.bool_, rep),
             _spec((K, v1, O), jnp.int32, rep),
             _spec((K, v1, O), jnp.bool_, rep),
@@ -140,3 +142,35 @@ def test_device_segment_compiles_sharded_over_four_chips(topo, monkeypatch):
         if m:
             n = int(np.prod([int(d) for d in m.group(1).split(",") if d]))
             assert n < K * B * C // 4, line.strip()[:160]
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_walk_start_compiles_for_v5e(topo, monkeypatch, chips):
+    """The walk's start (true counts and the break cache's build) at the
+    largest suite window; on four chips each chip builds its own chains'
+    cache, so no [K, B, C] or [K, B, V+1] tensor crosses chips."""
+    from repro.core.sat.walksat_jax import _walk_start
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    K, B, V, C, L, O = WINDOWS["sha2"]
+    mesh = None
+    if chips == 1:
+        rep = bat = SingleDeviceSharding(topo.devices[0])
+    else:
+        mesh = Mesh(np.asarray(topo.devices), ("dev",))
+        rep = NamedSharding(mesh, P())
+        bat = NamedSharding(mesh, P(None, "dev", None))
+    compiled = _walk_start.lower(
+        _spec((K, C, L), jnp.int32, rep), _spec((K, C, L), jnp.bool_, rep),
+        _spec((K, V + 1, O), jnp.int32, rep),
+        _spec((K, V + 1, O), jnp.bool_, rep),
+        _spec((K, B, V + 1), jnp.bool_, bat), "auto", mesh).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    tc, tsum, brk = compiled.out_info
+    assert tc.shape == tsum.shape == (K, B, C) and brk.shape == (K, B, V + 1)
+    for line in text.splitlines():
+        m = re.search(r"= \w+\[([\d,]*)\]\S* all-(?:gather|reduce|to-all)"
+                      r"(?:-start)?\(", line)
+        if m:
+            n = int(np.prod([int(d) for d in m.group(1).split(",") if d]))
+            assert n < K * B * (V + 1) // 4, line.strip()[:160]

@@ -3,6 +3,8 @@ its ``walk.*`` scope in its op_name metadata, and the scopes change no
 computed value on either engine, with the kernels or without."""
 import hashlib
 import json
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -36,13 +38,30 @@ def test_device_segment_carries_each_scope(kernels):
              _S((2,), jnp.uint32), _S((), jnp.int32), _S((), jnp.int32),
              _S((K,), jnp.bool_), _S((K, v1), jnp.bool_),
              _S((K,), jnp.bool_), _S((K,), jnp.int32),
-             _S((K, v1), jnp.bool_))
+             _S((K, v1), jnp.bool_),
+             _S((K, B, C), jnp.int32), _S((K, B, v1), jnp.int32),
+             _S((), jnp.int32))
     text = _device_segment.lower(
         _POLL_CHUNKS, 2.3, kernels, None, *_clauses(),
         _S((), jnp.int32), _S((), jnp.int32), state,
     ).as_text(debug_info=True)
     for scope in STEP + ("walk.chunk_end",):
         assert scope in text, scope
+    # the pick reads the break cache: no gather of the true counts at
+    # every occurrence of the picked clause's literals (B·L·O indices)
+    for n in _gather_index_counts(text):
+        assert n < B * L * O, n
+
+
+def _gather_index_counts(text):
+    """The number of indices of each stablehlo gather in ``text``."""
+    for line in text.splitlines():
+        m = re.search(r'stablehlo\.gather".*index_vector_dim = (\d+)'
+                      r'.*: \(tensor<[^>]*>, tensor<([\dx]*)x?i\d+>\)', line)
+        if m:
+            dims = [int(d) for d in m.group(2).split("x") if d]
+            ivd = int(m.group(1))
+            yield math.prod(dims) // (dims[ivd] if ivd < len(dims) else 1)
 
 
 @pytest.mark.parametrize("kernels", [None, "interpret"])
